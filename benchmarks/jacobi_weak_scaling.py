@@ -6,8 +6,11 @@ This is the paper's central claim transplanted to the TPU tier: the
 nonlocal-traffic gap grows linearly with blocks-per-device for the
 scattered schedule while staying constant for the locality schedule.
 
-Runs in a subprocess (needs multi-device host platform); emits CSV:
-devices,schedule,collective_bytes_per_dev,ratio
+The figures are bytes counted from compiled HLO, not times, so each device
+count runs in a child process pinned to the CPU (``JAX_PLATFORMS=cpu``,
+virtual host devices); no child touches an accelerator.  A child that fails
+fails the run.  Emits CSV:
+devices,schedule,collective_bytes_per_dev,ratio_vs_contiguous,platform
 """
 from __future__ import annotations
 
@@ -48,24 +51,26 @@ print("RESULT " + json.dumps(out))
 
 
 def main(device_counts=(4, 8)) -> list[str]:
-    lines = ["devices,schedule,collective_bytes_per_dev,ratio_vs_contiguous"]
+    lines = ["devices,schedule,collective_bytes_per_dev,ratio_vs_contiguous,"
+             "platform"]
     root = Path(__file__).resolve().parent.parent
     env = dict(os.environ)
     env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     for n in device_counts:
         proc = subprocess.run([sys.executable, "-c", _CHILD % {"n": n}],
                               env=env, capture_output=True, text=True,
                               timeout=600)
         if proc.returncode != 0:
-            lines.append(f"{n},ERROR,{proc.stderr[-120:]},")
-            continue
-        for ln in proc.stdout.splitlines():
-            if ln.startswith("RESULT "):
-                res = json.loads(ln[len("RESULT "):])
-                ratio = res["scattered"] / max(res["contiguous"], 1)
-                lines.append(f"{n},contiguous,{res['contiguous']:.0f},1.0")
-                lines.append(f"{n},scattered,{res['scattered']:.0f},{ratio:.1f}")
+            raise RuntimeError(f"{n}-device child failed "
+                               f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}")
+        res = next(json.loads(ln[len("RESULT "):])
+                   for ln in proc.stdout.splitlines()
+                   if ln.startswith("RESULT "))
+        ratio = res["scattered"] / max(res["contiguous"], 1)
+        lines.append(f"{n},contiguous,{res['contiguous']:.0f},1.0,cpu-hlo")
+        lines.append(f"{n},scattered,{res['scattered']:.0f},{ratio:.1f},cpu-hlo")
     return lines
 
 

@@ -9,7 +9,7 @@ from .ref import mha_ref
 
 def fused_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, use_pallas: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """(B, Hq, Tq, hd) x (B, Hkv, Tk, hd) -> (B, Hq, Tq, hd)."""
     if use_pallas:
         return flash_attention(q, k, v, causal=causal, window=window,

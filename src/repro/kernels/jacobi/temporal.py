@@ -78,7 +78,7 @@ def _temporal_kernel(c_ref, cc, nn, ss, ww, ee, nw, ne, sw, se, out_ref, *,
 @functools.partial(jax.jit, static_argnames=("di", "dj", "interpret"))
 def jacobi_two_step_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
                            di: int = 10, dj: int = 10,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """TWO Jacobi sweeps in one HBM pass over a (Ni, Nj, Nk) lattice.
 
     Requires di, dj >= 2 (2-deep halo must fit inside one neighbour block).

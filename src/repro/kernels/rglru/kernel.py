@@ -38,7 +38,7 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rglru_scan_pallas(a: jnp.ndarray, b: jnp.ndarray, chunk: int = 128,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """a, b: (B, T, W) f32; h0 = 0. Returns h (B, T, W)."""
     bt, t, w = a.shape
     if t % chunk:

@@ -9,7 +9,7 @@ from .ref import rglru_scan_ref
 
 
 def rglru_scan(a: jnp.ndarray, b: jnp.ndarray, use_pallas: bool = True,
-               interpret: bool = True, chunk: int = 128) -> jnp.ndarray:
+               interpret: bool = False, chunk: int = 128) -> jnp.ndarray:
     if use_pallas and a.shape[1] % chunk == 0:
         return rglru_scan_pallas(a, b, chunk=chunk, interpret=interpret)
     # associative-scan fallback (what the model layer uses on CPU)

@@ -53,7 +53,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, w, u, chunk: int = 64, interpret: bool = True):
+def wkv6_pallas(r, k, v, w, u, chunk: int = 64, interpret: bool = False):
     """r,k,v,w: (B, T, H, hd) f32; u: (H, hd). Returns (o, sT).
 
     Zero initial state (the model folds carried state outside the kernel).

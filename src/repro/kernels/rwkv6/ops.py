@@ -7,7 +7,7 @@ from .kernel import wkv6_pallas
 from .ref import wkv6_ref
 
 
-def wkv6(r, k, v, w, u, use_pallas: bool = True, interpret: bool = True,
+def wkv6(r, k, v, w, u, use_pallas: bool = True, interpret: bool = False,
          chunk: int = 64):
     """(o, sT) for the RWKV-6 recurrence with zero initial state."""
     if use_pallas and r.shape[1] % chunk == 0:

@@ -1,22 +1,28 @@
 """Serving driver: batched requests through the locality-queue router.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
         --requests 12 --replicas 3 --policy locality
 
-Compares router policies on the same workload (multi-turn sessions whose
-follow-ups have cache affinity to the replica that served turn one) and
-prints the locality/steal statistics next to the generated tokens.
+Runs the arch at its published width; ``--smoke`` shrinks it to the reduced
+same-family config (CPU).  Compares router policies on the same workload
+(multi-turn sessions whose follow-ups have cache affinity to the replica
+that served turn one) and prints the locality/steal statistics next to the
+generated tokens.
 """
 from __future__ import annotations
 
 import argparse
+from typing import Any
 
 import jax
 import numpy as np
 
 from ..configs import get_config, reduce_config
-from ..models.model import build_model
-from ..serving.engine import Request, ServingEngine
+from ..models.model import Model, build_model
+from ..serving.engine import Request, ServeStats, ServingEngine
+from .cache import use_compile_cache
+
+MAX_SEQ = 64
 
 
 def synth_requests(n: int, vocab: int, num_replicas: int,
@@ -32,10 +38,32 @@ def synth_requests(n: int, vocab: int, num_replicas: int,
     return reqs
 
 
+def build(arch: str, smoke: bool, seed: int = 0) -> tuple[Model, Any]:
+    """The model for ``arch`` (reduced if ``smoke``) and seeded params."""
+    cfg = get_config(arch)
+    if smoke:
+        cfg = reduce_config(cfg)
+    model = build_model(cfg, max_pos=256)
+    return model, jax.jit(model.init_params)(jax.random.key(seed))
+
+
+def serve(model: Model, params: Any, policy: str, requests: int,
+          replicas: int, seed: int = 0) -> tuple[list[Request], ServeStats]:
+    """Serve ``synth_requests`` under ``policy``; requests sorted by uid."""
+    engine = ServingEngine(model, params, num_replicas=replicas,
+                           max_seq=MAX_SEQ, policy=policy)
+    for req in synth_requests(requests, model.cfg.vocab_size, replicas,
+                              seed=seed):
+        engine.submit(req)
+    done = engine.run_until_drained()
+    return sorted(done, key=lambda r: r.uid), engine.stats
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced same-family config (CPU)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--replicas", type=int, default=3)
     ap.add_argument("--policy", default="locality",
@@ -43,21 +71,12 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = reduce_config(cfg)
-    model = build_model(cfg, max_pos=256)
-    params = model.init_params(jax.random.key(args.seed))
-
-    engine = ServingEngine(model, params, num_replicas=args.replicas,
-                           max_seq=64, policy=args.policy)
-    for req in synth_requests(args.requests, cfg.vocab_size, args.replicas,
-                              seed=args.seed):
-        engine.submit(req)
-    done = engine.run_until_drained()
-    for req in sorted(done, key=lambda r: r.uid)[:5]:
+    use_compile_cache()
+    model, params = build(args.arch, args.smoke, args.seed)
+    done, s = serve(model, params, args.policy, args.requests, args.replicas,
+                    args.seed)
+    for req in done[:5]:
         print(f"req {req.uid:3d} -> {req.out_tokens}")
-    s = engine.stats
     print(f"policy={args.policy} served={s.served} "
           f"local={s.locality_fraction:.2f} stolen={s.stolen} "
           f"prefill_tokens={s.prefill_tokens}")

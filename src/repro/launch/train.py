@@ -19,6 +19,7 @@ from ..data.pipeline import make_batch_iterator
 from ..models.model import build_model
 from ..train.loop import LoopConfig, Trainer
 from ..train.optimizer import AdamWConfig
+from .cache import use_compile_cache
 
 
 def main() -> None:
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_config(cfg)

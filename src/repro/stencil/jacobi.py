@@ -15,7 +15,9 @@ the lattice's i-axis is decomposed into slabs of blocks, and the
     remote planes — the halo volume (and hence the collective roofline term
     of the compiled HLO) inflates by ~``blocks_per_dev``x.
 
-The sweep body itself is the Pallas kernel (or its jnp oracle); the
+Both SPMD sweeps are XLA sweeps: each device applies the jitted
+``jacobi_sweep_ref`` to its halo-padded slabs (the Pallas kernel is the
+single-device path, ``repro.kernels.jacobi.ops.jacobi_sweep``).  The
 schedule builder of ``repro.core.assignment`` chooses the contiguous slabs
 when given block homes, demonstrating the end-to-end path
 placement → locality queues → SPMD assignment → fewer collective bytes.
@@ -23,7 +25,8 @@ placement → locality queues → SPMD assignment → fewer collective bytes.
 ``run_runtime_sweep`` adds a third, *online* execution path: slab updates
 submitted as tasks to the ``repro.runtime`` executor, with the paper's
 locality queues scheduling them dynamically (identical physics, observable
-local/steal statistics).
+local/steal statistics).  Each slab update is one jitted dispatch on the
+default device.
 """
 from __future__ import annotations
 
@@ -32,10 +35,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..kernels.jacobi.ops import jacobi_sweep
 from ..kernels.jacobi.ref import jacobi_sweep_ref
 from ..runtime import Executor, RuntimeStats, StealGovernor
 
@@ -45,9 +46,6 @@ class JacobiGridConfig:
     ni: int = 240
     nj: int = 60
     nk: int = 64
-    di: int = 10
-    dj: int = 10
-    dtype: str = "float32"
     axis: str = "data"          # mesh axis the i-axis is sharded over
 
 
@@ -68,22 +66,16 @@ def _halo_exchange(local: jnp.ndarray, axis: str) -> tuple[jnp.ndarray, jnp.ndar
     return up, down
 
 
-def make_contiguous_sweep(cfg: JacobiGridConfig, use_pallas: bool = False):
+def make_contiguous_sweep(cfg: JacobiGridConfig):
     """shard_map'd sweep with contiguous slab ownership (locality schedule)."""
 
     def sweep_local(f_local: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
         up, down = _halo_exchange(f_local, cfg.axis)
         padded = jnp.concatenate([up[None], f_local, down[None]], axis=0)
-        # interior update on the padded slab, then crop the halo rows.
-        if use_pallas:
-            # pad i to a block multiple for the kernel, update, crop.
-            out = jacobi_sweep(padded, use_pallas=False)
-        else:
-            out = jacobi_sweep_ref(padded)
-        out = out[1:-1]
-        # the ref applies Dirichlet at the padded-slab boundary, but rows
-        # 0/-1 of the crop saw the true halo planes, so values are exact.
-        return out
+        # interior update on the padded slab, then crop the halo rows: the
+        # ref applies Dirichlet at the padded-slab boundary, but rows 0/-1
+        # of the crop saw the true halo planes, so values are exact.
+        return jacobi_sweep_ref(padded)[1:-1]
 
     def sweep(f: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
         return jax.shard_map(
@@ -165,6 +157,27 @@ def scatter_lattice(f: jnp.ndarray, n_dev: int, blocks_per_dev: int) -> jnp.ndar
     return x.reshape(n_dev * blocks_per_dev * si, *f.shape[1:])
 
 
+@functools.partial(jax.jit, static_argnames=("di",), donate_argnums=(0,))
+def _update_slab(out: jax.Array, f: jax.Array, i0, c, di: int) -> jax.Array:
+    """Write the sweep of rows ``[i0, i0 + di)`` of ``f`` into ``out``.
+
+    ``i0`` is traced, so every slab shares one compiled program, and ``out``
+    is donated, so the update is in place.
+    """
+    ni = f.shape[0]
+    centre = jax.lax.dynamic_slice_in_dim(f, i0, di, axis=0)
+    up = jax.lax.dynamic_slice_in_dim(f, jnp.maximum(i0 - 1, 0), 1, axis=0)
+    down = jax.lax.dynamic_slice_in_dim(f, jnp.minimum(i0 + di, ni - 1), 1,
+                                        axis=0)
+    up = jnp.where(i0 > 0, up, jnp.zeros_like(up))
+    down = jnp.where(i0 + di < ni, down, jnp.zeros_like(down))
+    padded = jnp.concatenate([up, centre, down], axis=0)
+    # the ref applies Dirichlet at the padded-slab i-faces, but the crop
+    # keeps only rows that saw the true halo planes, so values are exact.
+    slab = jacobi_sweep_ref(padded, c)[1:-1]
+    return jax.lax.dynamic_update_slice_in_dim(out, slab, i0, axis=0)
+
+
 def run_runtime_sweep(f, c: float = 1.0 / 6.0, di: int = 10,
                       num_domains: int = 4, workers_per_domain: int = 1,
                       steal_order: str = "cyclic",
@@ -172,17 +185,19 @@ def run_runtime_sweep(f, c: float = 1.0 / 6.0, di: int = 10,
                       pool_cap: int = 256,
                       seed: int = 0,
                       trace=None,
-                      spec=None) -> tuple[np.ndarray, RuntimeStats]:
+                      spec=None) -> tuple[jax.Array, RuntimeStats]:
     """One whole-lattice sweep executed as online runtime tasks.
 
     The third execution path next to the shard_map'd SPMD sweeps above: the
     i-axis is cut into slabs of ``di`` rows, each slab update is one
     ``runtime.Task`` homed on a locality domain (contiguous slab→domain
     map = the paper's parallel first touch), and a ``runtime.Executor``
-    schedules them.  A Jacobi sweep reads only the *old* array, so tasks
-    commute and any schedule yields the exact ``jacobi_sweep_ref`` answer —
-    the scheduling policy changes the local/steal statistics, never the
-    physics.  Returns ``(new_lattice, runtime_stats)``.
+    schedules them.  Each task is one jitted dispatch that updates its slab
+    of the output in place on the default device.  A Jacobi sweep reads
+    only the *old* array, so tasks commute and any schedule yields the
+    exact ``jacobi_sweep_ref`` answer — the scheduling policy changes the
+    local/steal statistics, never the physics.  Returns
+    ``(new_lattice, runtime_stats)``.
 
     ``trace`` takes an optional ``repro.trace.TraceRecorder``: the sweep's
     slab-task schedule is then recorded for offline steal-storm analysis
@@ -195,23 +210,17 @@ def run_runtime_sweep(f, c: float = 1.0 / 6.0, di: int = 10,
     then ignored, and a recorded trace embeds the spec so ``replay(trace)``
     reconstructs the schedule with no factory).
     """
-    f = np.asarray(f)
+    f = jnp.asarray(f)
     ni = f.shape[0]
     if ni % di != 0:
         raise ValueError(f"i extent {ni} not divisible by slab size {di}")
     nslabs = ni // di
-    out = np.empty_like(f)
-    zero_plane = np.zeros_like(f[0])
+    out = jnp.zeros_like(f)
+    c = jnp.asarray(c, f.dtype)
 
     def update_slab(task, worker):
-        s = task.payload
-        i0 = s * di
-        up = f[i0 - 1] if i0 > 0 else zero_plane
-        down = f[i0 + di] if i0 + di < ni else zero_plane
-        padded = np.concatenate([up[None], f[i0:i0 + di], down[None]], axis=0)
-        # the ref applies Dirichlet at the padded-slab i-faces, but the crop
-        # keeps only rows that saw the true halo planes, so values are exact.
-        out[i0:i0 + di] = np.asarray(jacobi_sweep_ref(jnp.asarray(padded), c))[1:-1]
+        nonlocal out
+        out = _update_slab(out, f, task.payload * di, c, di=di)
 
     if spec is not None:
         if spec.trace.record:
