@@ -7,7 +7,12 @@ Runs the arch at its published width; ``--smoke`` shrinks it to the reduced
 same-family config (CPU).  Compares router policies on the same workload
 (multi-turn sessions whose follow-ups have cache affinity to the replica
 that served turn one) and prints the locality/steal statistics next to the
-generated tokens.
+generated tokens.  Each request's own timeline (``Request.timing``) is
+printed as one line: the replica that served it, its queue wait (submit to
+grab), its prefill (grab to first token, with the host time of the cache
+set-up and of the prefill dispatch within it) and the host microseconds per
+later token spent dispatching the decode step, sampling and fetching the
+token.
 """
 from __future__ import annotations
 
@@ -59,6 +64,20 @@ def serve(model: Model, params: Any, policy: str, requests: int,
     return sorted(done, key=lambda r: r.uid), engine.stats
 
 
+def timeline_line(req: Request) -> str:
+    """One request's timeline as the operator reads it."""
+    t = req.timing
+    per_tok = 1e6 / max(len(req.out_tokens) - 1, 1)
+    return (f"req {req.uid:3d} replica={t.replica} "
+            f"queue_ms={(t.t_grab - t.t_submit) * 1e3:.3f} "
+            f"prefill_ms={(t.t_first - t.t_grab) * 1e3:.3f} "
+            f"(cache {t.cache_init_s * 1e3:.3f}, "
+            f"dispatch {t.prefill_s * 1e3:.3f}) "
+            f"per_token_us dispatch={t.dispatch_s * per_tok:.1f} "
+            f"sample={t.sample_s * per_tok:.1f} "
+            f"fetch={t.fetch_s * per_tok:.1f}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
@@ -77,6 +96,8 @@ def main() -> None:
                     args.seed)
     for req in done[:5]:
         print(f"req {req.uid:3d} -> {req.out_tokens}")
+    for req in done:
+        print(timeline_line(req))
     print(f"policy={args.policy} served={s.served} "
           f"local={s.locality_fraction:.2f} stolen={s.stolen} "
           f"prefill_tokens={s.prefill_tokens}")

@@ -42,6 +42,13 @@ contract; a fused padded-batch decode is a later kernel-level step).  Pass
 plane (cost routing, adaptive batching, the steal circuit-breaker) to the
 engine's router.
 
+Each request records its own timeline (``Request.timing``, a ``Timing``):
+host ``time.perf_counter`` stamps at submit, at the start of the grab that
+serves it, at its first and last token fetched, the replica that served
+it, and the host seconds of each phase of ``Replica.run``.  The stamps are
+on the clock of the benchmark's spans, and a profiled run maps them onto
+the device trace's clock by one span's two ends.
+
 Spec construction (the preferred path): pass
 ``spec=repro.spec.RuntimeSpec`` with a ``serving`` block —
 ``spec.named("controlled_serving")`` is the canonical example — and the
@@ -56,6 +63,7 @@ the exact router with no hand-written factory.  The raw kwargs
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Optional
 
 import jax
@@ -70,12 +78,35 @@ POLICIES = ("locality", "round_robin", "single_queue")
 
 
 @dataclasses.dataclass
+class Timing:
+    """One request's timeline, in host ``time.perf_counter`` seconds.
+
+    The stamps are ``None`` until known; ``Replica.run`` called on its own
+    (no engine) leaves ``t_submit``, ``t_grab`` and ``replica`` unset.  The
+    three decode sums cover the tokens after the first, so that
+    ``dispatch_s + sample_s + fetch_s == t_last - t_first`` up to the cost
+    of the stamps themselves.
+    """
+    t_submit: Optional[float] = None    # ServingEngine.submit
+    t_grab: Optional[float] = None      # the grab that serves it starts
+    t_first: Optional[float] = None     # first token fetched to the host
+    t_last: Optional[float] = None      # last token fetched to the host
+    replica: int = -1                   # the worker that served it
+    cache_init_s: float = 0.0           # init_cache
+    prefill_s: float = 0.0              # dispatching the prefill program
+    dispatch_s: float = 0.0             # dispatching decode steps
+    sample_s: float = 0.0               # argmax over the last logits
+    fetch_s: float = 0.0                # the blocking fetch of each token
+
+
+@dataclasses.dataclass
 class Request:
     uid: int
     tokens: np.ndarray              # prompt tokens (1D)
     max_new: int
     home_replica: int = -1          # -1: no cached prefix anywhere
     out_tokens: list[int] = dataclasses.field(default_factory=list)
+    timing: Timing = dataclasses.field(default_factory=Timing)
 
 
 @dataclasses.dataclass
@@ -103,17 +134,40 @@ class Replica:
         self._decode = jax.jit(model.decode_step)
 
     def run(self, req: Request) -> Request:
-        model = self.model
+        clock, tm = time.perf_counter, req.timing
+        t0 = clock()
+        caches = self.model.init_cache(1, self.max_seq)
+        t1 = clock()
         toks = jnp.asarray(req.tokens, jnp.int32)[None]
-        caches = model.init_cache(1, self.max_seq)
         logits, caches = self._prefill(self.params, {"tokens": toks}, caches)
+        t2 = clock()
+        tm.cache_init_s, tm.prefill_s = t1 - t0, t2 - t1
         pos = toks.shape[1]
         cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        sampled = clock()
+        dispatch = sample = fetch = 0.0
+        fetched = dispatched = None
         for _ in range(req.max_new):
             req.out_tokens.append(int(cur[0, 0]))
+            now = clock()
+            if fetched is None:
+                tm.t_first = now
+            else:
+                fetch += now - sampled
+            fetched = now
             logits, caches = self._decode(self.params, cur, pos, caches)
+            dispatched = clock()
             cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+            sampled = clock()
+            dispatch += dispatched - fetched
+            sample += sampled - dispatched
             pos += 1
+        if fetched is not None:
+            # the step dispatched after the last token lies past t_last
+            tm.t_last = fetched
+            tm.dispatch_s = dispatch - (dispatched - fetched)
+            tm.sample_s = sample - (sampled - dispatched)
+            tm.fetch_s = fetch
         return req
 
     def run_batch(self, reqs: list[Request]) -> list[Request]:
@@ -249,10 +303,14 @@ class ServingEngine:
 
     def _run_grab(self, tasks: list[Task], worker: Worker) -> list[Request]:
         reqs = [self._touch(task.payload, worker) for task in tasks]
+        t = time.perf_counter()
+        for req in reqs:
+            req.timing.t_grab, req.timing.replica = t, worker.wid
         return self.replicas[worker.wid].run_batch(reqs)
 
     # -- public API ----------------------------------------------------------
     def submit(self, req: Request) -> None:
+        req.timing.t_submit = time.perf_counter()
         task = self._exec.make_task(payload=req, home=req.home_replica,
                                     cost=float(len(req.tokens)))
         if self.policy == "single_queue":

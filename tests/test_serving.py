@@ -106,3 +106,99 @@ class TestRouterPolicies:
             cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
             pos += 1
         assert done[0].out_tokens == expect
+
+
+def _plain_greedy(model, params, toks, max_new, max_seq=64):
+    """Prefill and greedy decode with no engine: the tokens to expect."""
+    import jax.numpy as jnp
+    caches = model.init_cache(1, max_seq)
+    logits, caches = model.prefill(
+        params, {"tokens": jnp.asarray(toks, jnp.int32)[None]}, caches)
+    pos, out = len(toks), []
+    cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+    for _ in range(max_new):
+        out.append(int(cur[0, 0]))
+        logits, caches = model.decode_step(params, cur, pos, caches)
+        cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+        pos += 1
+    return out
+
+
+POLICIES = ("locality", "round_robin", "single_queue")
+
+
+@pytest.fixture(scope="module")
+def timelines(small_model):
+    """Per policy: the served requests and, by uid, the index of the
+    replica whose ``run_batch`` served each."""
+    cfg, model, params = small_model
+    out = {}
+    for policy in POLICIES:
+        eng = ServingEngine(model, params, num_replicas=2, max_seq=64,
+                            policy=policy)
+        served_by = {}
+        for i, rep in enumerate(eng.replicas):
+            def grab(reqs, _run=rep.run_batch, _i=i):
+                served_by.update((r.uid, _i) for r in reqs)
+                return _run(reqs)
+            rep.run_batch = grab
+        for r in _requests(cfg, n=6, seed=4):
+            r.max_new = 6
+            eng.submit(r)
+        out[policy] = (eng.run_until_drained(), served_by)
+    return out
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+class TestRequestTimeline:
+    def test_timeline_is_ordered(self, timelines, policy):
+        done, _ = timelines[policy]
+        assert len(done) == 6
+        for r in done:
+            t = r.timing
+            assert t.t_submit <= t.t_grab <= t.t_first <= t.t_last
+            assert t.cache_init_s > 0 and t.prefill_s > 0
+
+    def test_replica_names_the_worker_that_served(self, timelines, policy):
+        done, served_by = timelines[policy]
+        assert {r.uid: r.timing.replica for r in done} == served_by
+
+    def test_decode_sums_make_up_first_to_last_token(self, timelines, policy):
+        done, _ = timelines[policy]
+        for r in done:
+            t = r.timing
+            span = t.t_last - t.t_first
+            parts = t.dispatch_s + t.sample_s + t.fetch_s
+            assert span > 0 and min(t.dispatch_s, t.sample_s, t.fetch_s) > 0
+            assert abs(parts - span) <= 0.05 * span
+
+    def test_tokens_are_unchanged(self, small_model, timelines, policy):
+        cfg, model, params = small_model
+        done, _ = timelines[policy]
+        want = {r.uid: _plain_greedy(model, params, r.tokens, 6)
+                for r in _requests(cfg, n=6, seed=4)}
+        assert {r.uid: r.out_tokens for r in done} == want
+
+
+def test_replica_run_alone_leaves_the_engine_stamps_unset(small_model):
+    from repro.serving.engine import Replica
+    cfg, model, params = small_model
+    req = Replica(model, params, 64).run(
+        Request(uid=0, tokens=np.arange(5), max_new=3))
+    t = req.timing
+    assert t.t_submit is None and t.t_grab is None and t.replica == -1
+    assert t.t_first <= t.t_last and len(req.out_tokens) == 3
+
+
+def test_operator_line_reads_each_request_timeline(small_model):
+    from repro.launch.serve import serve, timeline_line
+    cfg, model, params = small_model
+    done, _ = serve(model, params, "locality", requests=3, replicas=2)
+    for req in done:
+        line = timeline_line(req)
+        assert line.startswith(f"req {req.uid:3d} replica={req.timing.replica} ")
+        fields = dict(kv.split("=") for kv in line.replace(",", "").split()
+                      if "=" in kv)
+        assert set(fields) == {"replica", "queue_ms", "prefill_ms", "dispatch",
+                               "sample", "fetch"}
+        assert all(float(v) >= 0 for v in fields.values())
