@@ -11,8 +11,13 @@ generated tokens.  Each request's own timeline (``Request.timing``) is
 printed as one line: the replica that served it, its queue wait (submit to
 grab), its prefill (grab to first token, with the host time of the cache
 set-up and of the prefill dispatch within it) and the host microseconds per
-later token spent dispatching the decode step, sampling and fetching the
-token.
+later token spent dispatching the decode step, handing off to the fetch and
+fetching the token.  The greedy sample runs on the device inside the
+prefill and decode programs, and each decode step is dispatched before the
+token ahead of it is fetched: ``sample`` is now only the host's hand-off
+from a dispatch to the fetch (about a microsecond), and
+``Request.timing.decode_steps`` counts the decode programs dispatched,
+``max_new - 1`` a request.
 """
 from __future__ import annotations
 

@@ -23,7 +23,9 @@ Routing policies:
   ``single_queue`` — one shared FIFO (a single locality domain): replicas
                      take work in arrival order, locality is accidental.
 
-The engine runs the real model (prefill + decode steps) for every request;
+The engine runs the real model (prefill + decode steps) for every request,
+each step one jitted program with its greedy sample (``greedy_prefill``,
+``greedy_decode_step``) that every replica of the model shares;
 tests/test_serving.py checks the outputs are identical under every routing
 policy while the steal/local statistics differ as the paper predicts.
 
@@ -63,6 +65,7 @@ the exact router with no hand-written factory.  The raw kwargs
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Optional
 
@@ -83,9 +86,12 @@ class Timing:
 
     The stamps are ``None`` until known; ``Replica.run`` called on its own
     (no engine) leaves ``t_submit``, ``t_grab`` and ``replica`` unset.  The
-    three decode sums cover the tokens after the first, so that
-    ``dispatch_s + sample_s + fetch_s == t_last - t_first`` up to the cost
-    of the stamps themselves.
+    greedy sample runs on the device, inside the prefill and decode
+    programs, and the loop dispatches decode step n+1 before it fetches
+    token n.  The three decode sums cover the tokens after the first, so
+    that ``dispatch_s + sample_s + fetch_s == t_last - t_first`` up to the
+    cost of the stamps themselves; the first decode step is dispatched
+    before the first token's fetch, so it lies in ``t_first - t_grab``.
     """
     t_submit: Optional[float] = None    # ServingEngine.submit
     t_grab: Optional[float] = None      # the grab that serves it starts
@@ -95,8 +101,9 @@ class Timing:
     cache_init_s: float = 0.0           # init_cache
     prefill_s: float = 0.0              # dispatching the prefill program
     dispatch_s: float = 0.0             # dispatching decode steps
-    sample_s: float = 0.0               # argmax over the last logits
+    sample_s: float = 0.0               # hand-off from a dispatch to a fetch
     fetch_s: float = 0.0                # the blocking fetch of each token
+    decode_steps: int = 0               # decode programs dispatched
 
 
 @dataclasses.dataclass
@@ -121,6 +128,30 @@ class ServeStats:
         return self.local / max(self.served, 1)
 
 
+def _greedy(logits: jax.Array) -> jax.Array:
+    """The greedy token of the last position: (batch, 1) int32."""
+    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+
+
+# The model is a static argument, so every replica of one model shares one
+# compiled program per shape.
+@functools.partial(jax.jit, static_argnums=0)
+def greedy_prefill(model: Model, params: Any, tokens: jax.Array,
+                   caches: Any) -> tuple[jax.Array, Any]:
+    """``model.prefill`` and the greedy sample, as one program."""
+    logits, caches = model.prefill(params, {"tokens": tokens}, caches)
+    return _greedy(logits), caches
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def greedy_decode_step(model: Model, params: Any, token: jax.Array, pos,
+                       caches: Any) -> tuple[jax.Array, Any]:
+    """``model.decode_step`` on the last token and the greedy sample, as one
+    program; ``pos`` is traced, so no position compiles a program."""
+    logits, caches = model.decode_step(params, token, pos, caches)
+    return _greedy(logits), caches
+
+
 class Replica:
     """One model replica with its own KV-cache arena."""
 
@@ -130,44 +161,45 @@ class Replica:
         self.params = params
         self.max_seq = max_seq
         self.batch = batch_size
-        self._prefill = jax.jit(model.prefill)
-        self._decode = jax.jit(model.decode_step)
 
     def run(self, req: Request) -> Request:
+        """Prefill and greedy decode ``req.max_new`` tokens, appending each
+        to ``req.out_tokens`` as soon as it reaches the host.  Decode step
+        n+1 is dispatched before token n is fetched, so the device has the
+        next step queued while the host waits; ``max_new - 1`` decode steps
+        run in all."""
         clock, tm = time.perf_counter, req.timing
         t0 = clock()
         caches = self.model.init_cache(1, self.max_seq)
         t1 = clock()
-        toks = jnp.asarray(req.tokens, jnp.int32)[None]
-        logits, caches = self._prefill(self.params, {"tokens": toks}, caches)
+        prompt = np.asarray(req.tokens, np.int32)[None]
+        tok, caches = greedy_prefill(self.model, self.params, prompt, caches)
+        tok.copy_to_host_async()
         t2 = clock()
         tm.cache_init_s, tm.prefill_s = t1 - t0, t2 - t1
-        pos = toks.shape[1]
-        cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        sampled = clock()
+        pos = prompt.shape[1]
         dispatch = sample = fetch = 0.0
-        fetched = dispatched = None
-        for _ in range(req.max_new):
-            req.out_tokens.append(int(cur[0, 0]))
-            now = clock()
-            if fetched is None:
-                tm.t_first = now
-            else:
-                fetch += now - sampled
-            fetched = now
-            logits, caches = self._decode(self.params, cur, pos, caches)
+        last = t2
+        for i in range(req.max_new):
+            nxt = None
+            if i + 1 < req.max_new:
+                nxt, caches = greedy_decode_step(self.model, self.params,
+                                                 tok, pos + i, caches)
+                nxt.copy_to_host_async()
+                tm.decode_steps += 1
             dispatched = clock()
-            cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-            sampled = clock()
-            dispatch += dispatched - fetched
-            sample += sampled - dispatched
-            pos += 1
-        if fetched is not None:
-            # the step dispatched after the last token lies past t_last
-            tm.t_last = fetched
-            tm.dispatch_s = dispatch - (dispatched - fetched)
-            tm.sample_s = sample - (sampled - dispatched)
-            tm.fetch_s = fetch
+            handed = clock()
+            req.out_tokens.append(int(np.asarray(tok)[0, 0]))
+            fetched = clock()
+            if i == 0:
+                tm.t_first = fetched
+            else:
+                dispatch += dispatched - last
+                sample += handed - dispatched
+                fetch += fetched - handed
+            tm.t_last = last = fetched
+            tok = nxt
+        tm.dispatch_s, tm.sample_s, tm.fetch_s = dispatch, sample, fetch
         return req
 
     def run_batch(self, reqs: list[Request]) -> list[Request]:

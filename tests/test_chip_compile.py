@@ -20,6 +20,7 @@ from repro.kernels.jacobi.kernel import jacobi_sweep_pallas
 from repro.kernels.jacobi.ref import jacobi_sweep_ref
 from repro.launch.serve import MAX_SEQ
 from repro.models.model import build_model
+from repro.serving.engine import greedy_decode_step, greedy_prefill
 from repro.stencil.jacobi import (JacobiGridConfig, _update_slab,
                                   make_contiguous_sweep, make_scattered_sweep)
 
@@ -92,6 +93,8 @@ def test_reference_and_slab_update_paper_grid(one_chip):
 
 @pytest.mark.parametrize("step", ["prefill", "decode_step"])
 def test_qwen2_full_width_one_chip(one_chip, step):
+    """The serving engine's programs: each model step with its greedy
+    sample."""
     cfg = get_config("qwen2-0.5b")
     model = build_model(cfg, max_pos=256)
 
@@ -103,12 +106,11 @@ def test_qwen2_full_width_one_chip(one_chip, step):
     caches = on_chip(jax.eval_shape(lambda: model.init_cache(1, MAX_SEQ)))
     if step == "prefill":
         toks = on_chip(jax.ShapeDtypeStruct((1, 16), jnp.int32))
-        lowered = jax.jit(model.prefill).lower(params, {"tokens": toks},
-                                               caches)
+        lowered = greedy_prefill.lower(model, params, toks, caches)
     else:
         toks = on_chip(jax.ShapeDtypeStruct((1, 1), jnp.int32))
         pos = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
-        lowered = jax.jit(model.decode_step).lower(params, toks, pos, caches)
+        lowered = greedy_decode_step.lower(model, params, toks, pos, caches)
     compiled = lowered.compile()
     assert jax.tree.leaves(params)[0].dtype == jnp.bfloat16
     assert _bytes(compiled) <= HBM_BYTES

@@ -5,7 +5,8 @@ import pytest
 
 from repro.configs import get_config, reduce_config
 from repro.models.model import build_model
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import (Replica, Request, ServingEngine, Timing,
+                                  greedy_decode_step, greedy_prefill)
 
 
 @pytest.fixture(scope="module")
@@ -14,6 +15,19 @@ def small_model():
     model = build_model(cfg, max_pos=96)
     params = model.init_params(jax.random.key(0))
     return cfg, model, params
+
+
+POLICIES = ("locality", "round_robin", "single_queue")
+
+
+@pytest.fixture(scope="module")
+def greedy_expect(small_model):
+    """Three prompts, each with the seven tokens ``_plain_greedy`` decodes
+    after it (greedy, so any shorter answer is a prefix of these)."""
+    cfg, model, params = small_model
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n) for n in (5, 7, 11)]
+    return [(toks, _plain_greedy(model, params, toks, 7)) for toks in prompts]
 
 
 def _requests(cfg, n=8, replicas=2, seed=0):
@@ -85,27 +99,29 @@ class TestRouterPolicies:
             tr, steal_penalty=lambda task, w: task.cost))
         assert res.stats["executed"] == 8
 
-    def test_greedy_decode_matches_model(self, small_model):
-        """Engine output == hand-rolled prefill+argmax decode."""
+    @pytest.mark.parametrize("max_new", (1, 2, 7))
+    @pytest.mark.parametrize("runner", ("replica",) + POLICIES)
+    def test_greedy_decode_matches_model(self, small_model, greedy_expect,
+                                         runner, max_new):
+        """Tokens == hand-rolled prefill+argmax decode, through
+        ``Replica.run`` alone and through the engine under every policy,
+        with one decode program dispatched per token after the first."""
         cfg, model, params = small_model
-        import jax.numpy as jnp
-        toks = np.arange(7) % cfg.vocab_size
-        eng = ServingEngine(model, params, num_replicas=1, max_seq=64)
-        eng.submit(Request(uid=0, tokens=toks, max_new=3))
-        done = eng.run_until_drained()
-
-        caches = model.init_cache(1, 64)
-        logits, caches = model.prefill(
-            params, {"tokens": jnp.asarray(toks, jnp.int32)[None]}, caches)
-        pos = len(toks)
-        cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-        expect = []
-        for _ in range(3):
-            expect.append(int(cur[0, 0]))
-            logits, caches = model.decode_step(params, cur, pos, caches)
-            cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            pos += 1
-        assert done[0].out_tokens == expect
+        reqs = [Request(uid=i, tokens=toks, max_new=max_new)
+                for i, (toks, _) in enumerate(greedy_expect)]
+        if runner == "replica":
+            rep = Replica(model, params, 64)
+            done = [rep.run(r) for r in reqs]
+        else:
+            eng = ServingEngine(model, params, num_replicas=2, max_seq=64,
+                                policy=runner)
+            for r in reqs:
+                eng.submit(r)
+            done = eng.run_until_drained()
+        assert len(done) == len(reqs)
+        for r in done:
+            assert r.out_tokens == greedy_expect[r.uid][1][:max_new]
+            assert r.timing.decode_steps == max_new - 1
 
 
 def _plain_greedy(model, params, toks, max_new, max_seq=64):
@@ -122,9 +138,6 @@ def _plain_greedy(model, params, toks, max_new, max_seq=64):
         cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
         pos += 1
     return out
-
-
-POLICIES = ("locality", "round_robin", "single_queue")
 
 
 @pytest.fixture(scope="module")
@@ -181,7 +194,6 @@ class TestRequestTimeline:
 
 
 def test_replica_run_alone_leaves_the_engine_stamps_unset(small_model):
-    from repro.serving.engine import Replica
     cfg, model, params = small_model
     req = Replica(model, params, 64).run(
         Request(uid=0, tokens=np.arange(5), max_new=3))
@@ -202,3 +214,51 @@ def test_operator_line_reads_each_request_timeline(small_model):
         assert set(fields) == {"replica", "queue_ms", "prefill_ms", "dispatch",
                                "sample", "fetch"}
         assert all(float(v) >= 0 for v in fields.values())
+
+
+class _StepsAtAppend(list):
+    """A token list that records, at each append, how many decode steps
+    the request had dispatched."""
+
+    def __init__(self, timing):
+        super().__init__()
+        self.timing, self.steps = timing, []
+
+    def append(self, tok) -> None:
+        self.steps.append(self.timing.decode_steps)
+        super().append(tok)
+
+
+@pytest.mark.parametrize("max_new", (1, 2, 7))
+def test_each_token_is_appended_one_step_ahead(small_model, greedy_expect,
+                                               max_new):
+    """Token n reaches the caller's list alone, once step n+1 (if any) is
+    dispatched and before step n+2 is."""
+    cfg, model, params = small_model
+    toks, want = greedy_expect[0]
+    timing = Timing()
+    out = _StepsAtAppend(timing)
+    req = Request(uid=0, tokens=toks, max_new=max_new, out_tokens=out,
+                  timing=timing)
+    assert Replica(model, params, 64).run(req).out_tokens is out
+    assert out == want[:max_new]
+    assert out.steps == [min(n + 1, max_new - 1) for n in range(max_new)]
+
+
+def test_replicas_share_one_compiled_program_per_shape(small_model):
+    """Four replicas of one model compile one decode program and one
+    prefill program per prompt length between them."""
+    cfg, _, params = small_model
+    model = build_model(cfg, max_pos=96)     # a model nothing compiled yet
+    eng = ServingEngine(model, params, num_replicas=4, max_seq=64,
+                        policy="round_robin")
+    prefills = greedy_prefill._cache_size()
+    decodes = greedy_decode_step._cache_size()
+    rng = np.random.default_rng(5)
+    for i in range(8):
+        toks = rng.integers(0, cfg.vocab_size, size=(6, 9)[i % 2])
+        eng.submit(Request(uid=i, tokens=toks, max_new=3))
+    done = eng.run_until_drained()
+    assert {r.timing.replica for r in done} == {0, 1, 2, 3}
+    assert greedy_prefill._cache_size() - prefills == 2
+    assert greedy_decode_step._cache_size() - decodes == 1
