@@ -6,6 +6,7 @@ from .base import (
     MLAConfig,
     ModelConfig,
     MoEConfig,
+    RopeScaling,
     ShapeConfig,
     VisionConfig,
     cell_is_applicable,
@@ -22,6 +23,7 @@ def _load_all() -> None:
     if _LOADED:
         return
     from . import (  # noqa: F401
+        deepseek_v2_lite,
         gemma3_1b,
         llama32_vision_90b,
         minicpm3_4b,
@@ -59,11 +61,14 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         # capacity_factor high enough that nothing is dropped: exact
         # prefill/decode equivalence is testable (capacity-drop behaviour
         # itself is covered by the MoE unit tests)
+        cap = None if cfg.moe.capacity_factor is None else 8.0
         kw["moe"] = dataclasses.replace(cfg.moe, num_experts=8, top_k=2,
-                                        d_ff_expert=32, capacity_factor=8.0)
+                                        d_ff_expert=32, capacity_factor=cap,
+                                        experts_held=None, expert_offset=0)
         kw["d_ff"] = 32
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
+        kw["mla"] = MLAConfig(q_lora_rank=32 if cfg.mla.q_lora_rank else 0,
+                              kv_lora_rank=16,
                               qk_nope_head_dim=16, qk_rope_head_dim=8,
                               v_head_dim=16)
     if cfg.encoder is not None:

@@ -17,7 +17,9 @@ prefill and decode programs, and each decode step is dispatched before the
 token ahead of it is fetched: ``sample`` is now only the host's hand-off
 from a dispatch to the fetch (about a microsecond), and
 ``Request.timing.decode_steps`` counts the decode programs dispatched,
-``max_new - 1`` a request.
+``max_new - 1`` a request.  For a mixture-of-experts model the line also
+gives the routed (token, choice) pairs over its MoE layers and how many of
+them landed on an expert this replica holds.
 """
 from __future__ import annotations
 
@@ -80,7 +82,9 @@ def timeline_line(req: Request) -> str:
             f"dispatch {t.prefill_s * 1e3:.3f}) "
             f"per_token_us dispatch={t.dispatch_s * per_tok:.1f} "
             f"sample={t.sample_s * per_tok:.1f} "
-            f"fetch={t.fetch_s * per_tok:.1f}")
+            f"fetch={t.fetch_s * per_tok:.1f} "
+            f"expert_choices={t.expert_choices} "
+            f"held_expert_choices={t.held_expert_choices}")
 
 
 def main() -> None:

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..distributed.sharding import shard
-from .common import Params, apply_rope, dense_init, matmul_lowp, split_keys
+from .common import (Params, apply_rope, dense_init, matmul_lowp, rope_freqs,
+                     split_keys)
 
 NEG_INF = -2.0e38
 
@@ -242,8 +243,7 @@ def seq_parallel_attention(q, k, v, *, pos_offset, window: int,
     sequence shard inside a shard_map.  This is the piece plain
     jit+constraints cannot express: ``lax.scan`` cannot iterate a sharded
     axis, so without shard_map XLA gathers q and every model-rank computes
-    every chunk (16x redundant flops + full-size score traffic — see
-    EXPERIMENTS.md §Perf-1/3).
+    every chunk (16x redundant flops + full-size score traffic).
     Returns None when not applicable (caller falls back).
     """
     if rules is None:
@@ -348,8 +348,9 @@ def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     b, tq = q.shape[:2]
     positions = pos_offset + jnp.arange(tq)
     if theta:
-        q = apply_rope(q, jnp.broadcast_to(positions, (b, tq)), theta)
-        k = apply_rope(k, jnp.broadcast_to(positions, (b, tq)), theta)
+        inv_freq = rope_freqs(q.shape[-1], theta)
+        q = apply_rope(q, jnp.broadcast_to(positions, (b, tq)), inv_freq)
+        k = apply_rope(k, jnp.broadcast_to(positions, (b, tq)), inv_freq)
 
     if cache is not None and tq == 1:
         # decode: append to (ring) cache, attend against it

@@ -49,7 +49,7 @@ def rms_norm(p: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     Statistics in f32, application in the input dtype: keeping the (B,T,D)
     tensor (and hence its cotangent, and hence every cross-shard psum of
     the residual stream) in bf16 halves TP wire traffic vs upcasting x
-    wholesale (EXPERIMENTS.md §Perf-2)."""
+    wholesale."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     mult = (jax.lax.rsqrt(var + eps)
             * (1.0 + p["scale"].astype(jnp.float32))).astype(x.dtype)
@@ -77,15 +77,56 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., T, H, hd); positions: broadcastable to (..., T)."""
-    hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
-    ang = positions[..., None].astype(jnp.float32) * freqs   # (..., T, hd/2)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor for a context ``factor`` times
+    the original."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_len: int,
+                          beta_fast: float, beta_slow: float) -> tuple[int, int]:
+    """The frequency indices between which YaRN blends: below ``low`` a
+    frequency turns more than ``beta_fast`` times over the original
+    context and is kept; from ``high`` on it is interpolated."""
+    def index(rotations):
+        return (dim * math.log(original_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(index(beta_fast))
+    high = math.ceil(index(beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_freqs(dim: int, theta: float, scaling) -> jnp.ndarray:
+    """YaRN's inverse frequencies (``scaling``: a ``RopeScaling``): the
+    plain ones below the correction range, the plain ones over
+    ``scaling.factor`` above it, and a linear ramp between."""
+    plain = rope_freqs(dim, theta)
+    low, high = yarn_correction_range(
+        dim, theta, scaling.original_max_position_embeddings,
+        scaling.beta_fast, scaling.beta_slow)
+    high = high if high > low else low + 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return plain / scaling.factor * ramp + plain * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: jnp.ndarray,
+               interleaved: bool = False) -> jnp.ndarray:
+    """x: (..., T, H, hd); positions: broadcastable to (..., T); inv_freq:
+    (hd/2,).  Dims i and i + hd/2 rotate together by ``inv_freq[i]``, or
+    with ``interleaved`` dims 2i and 2i+1 (DeepSeek-V2's published
+    layout)."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq   # (..., T, hd/2)
     cos = jnp.cos(ang)[..., None, :]                    # (..., T, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -109,7 +150,6 @@ def matmul_lowp(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     projection.  Requesting a bf16 result dtype makes the partials (and the
     all-reduce) bf16; the MXU still accumulates each local dot in f32
     internally, so only the ≤16-way cross-shard addition runs in bf16.
-    (EXPERIMENTS.md §Perf-2.)
     """
     if a.dtype == jnp.bfloat16 and b.dtype == jnp.bfloat16:
         return jnp.matmul(a, b, preferred_element_type=jnp.bfloat16)

@@ -1,7 +1,11 @@
 """Multi-head Latent Attention (MiniCPM3 / DeepSeek-V2 style).
 
-Queries come from a low-rank down/up projection; keys/values from a shared
-compressed latent ``c_kv`` (kv_lora_rank) plus a single shared rotary key.
+Queries come from a low-rank down/up projection, or (``q_lora_rank`` 0 or
+None, DeepSeek-V2-Lite) from one direct projection; keys/values from a
+shared compressed latent ``c_kv`` (kv_lora_rank) plus a single shared
+rotary key.  The rotary dims rotate in adjacent pairs, DeepSeek-V2's
+published layout, at YaRN's frequencies where the config scales rotary
+positions; YaRN's temperature then multiplies the softmax scale.
 The decode cache stores only (c_kv, k_rope) — (kv_lora + rope_dim) floats
 per token instead of 2 * H * hd: for MiniCPM3-4B that is 288 vs 5120 per
 token, an ~18x KV-cache reduction, which is the arch's whole point.
@@ -15,7 +19,8 @@ import jax.numpy as jnp
 
 from ..configs.base import MLAConfig, ModelConfig
 from ..distributed.sharding import shard
-from .common import Params, apply_rope, dense_init, rms_norm, rms_norm_init, split_keys
+from .common import (Params, apply_rope, dense_init, rms_norm, rms_norm_init,
+                     rope_freqs, split_keys, yarn_freqs, yarn_mscale)
 from .attention import NEG_INF, _causal_mask
 
 
@@ -24,10 +29,14 @@ def mla_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     d, h = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = split_keys(key, 7)
+    if m.q_lora_rank:
+        q = {"w_dq": dense_init(ks[0], d, m.q_lora_rank, dtype),
+             "q_norm": rms_norm_init(m.q_lora_rank, dtype),
+             "w_uq": dense_init(ks[1], m.q_lora_rank, h * qk, dtype)}
+    else:
+        q = {"w_q": dense_init(ks[0], d, h * qk, dtype)}
     return {
-        "w_dq": dense_init(ks[0], d, m.q_lora_rank, dtype),
-        "q_norm": rms_norm_init(m.q_lora_rank, dtype),
-        "w_uq": dense_init(ks[1], m.q_lora_rank, h * qk, dtype),
+        **q,
         "w_dkv": dense_init(ks[2], d, m.kv_lora_rank, dtype),
         "kv_norm": rms_norm_init(m.kv_lora_rank, dtype),
         "w_uk": dense_init(ks[3], m.kv_lora_rank, h * m.qk_nope_head_dim, dtype),
@@ -35,6 +44,20 @@ def mla_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
         "w_kr": dense_init(ks[5], d, m.qk_rope_head_dim, dtype),
         "wo": dense_init(ks[6], h * m.v_head_dim, d, dtype),
     }
+
+
+def rope_and_scale(cfg: ModelConfig):
+    """(inverse frequencies of the rotary dims, the factor on the rotated
+    dims, the softmax scale)."""
+    m, sc = cfg.mla, cfg.rope_scaling
+    dr = m.qk_rope_head_dim
+    scale = (m.qk_nope_head_dim + dr) ** -0.5
+    if sc is None:
+        return rope_freqs(dr, cfg.rope_theta), 1.0, scale
+    factor = yarn_mscale(sc.factor, sc.mscale) / yarn_mscale(sc.factor, sc.mscale_all_dim)
+    if sc.mscale_all_dim:
+        scale *= yarn_mscale(sc.factor, sc.mscale_all_dim) ** 2
+    return yarn_freqs(dr, cfg.rope_theta, sc), factor, scale
 
 
 def _absorbed_chunked_local(q_lat, q_rope, ckv, kr, q_offset, scale,
@@ -123,19 +146,23 @@ def mla_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     h = cfg.num_heads
     b, t, _ = x.shape
 
-    q = rms_norm(p["q_norm"], x @ p["w_dq"]) @ p["w_uq"]
+    if "w_q" in p:
+        q = x @ p["w_q"]
+    else:
+        q = rms_norm(p["q_norm"], x @ p["w_dq"]) @ p["w_uq"]
     q = q.reshape(b, t, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
 
     ckv_new = rms_norm(p["kv_norm"], x @ p["w_dkv"])          # (B,T,r)
     kr_new = x @ p["w_kr"]                                     # (B,T,dr)
 
-    positions = pos_offset + jnp.arange(t)
-    q_rope = apply_rope(q_rope, jnp.broadcast_to(positions, (b, t)),
-                        cfg.rope_theta)
-    kr_new = apply_rope(kr_new[:, :, None, :],
-                        jnp.broadcast_to(positions, (b, t)),
-                        cfg.rope_theta)[:, :, 0]
+    inv_freq, rope_factor, scale = rope_and_scale(cfg)
+    positions = jnp.broadcast_to(pos_offset + jnp.arange(t), (b, t))
+    q_rope = apply_rope(q_rope, positions, inv_freq, interleaved=True)
+    kr_new = apply_rope(kr_new[:, :, None, :], positions, inv_freq,
+                        interleaved=True)[:, :, 0]
+    if rope_factor != 1.0:
+        q_rope, kr_new = q_rope * rope_factor, kr_new * rope_factor
 
     new_cache = None
     if cache is not None:
@@ -151,15 +178,15 @@ def mla_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         ckv, kr = ckv_new, kr_new
         s = t
 
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-
     if t > 2048 and cache is None:
         # TRAINING at long seq: the absorbed form's r=288 contraction costs
         # ~3x the score flops and its backward re-pays it twice more —
-        # expansion + sequence-parallel attention wins (§Perf-1, iter 1c).
+        # expansion + sequence-parallel attention is cheaper.
         from .attention import seq_parallel_attention, chunked_attention
         from ..distributed.sharding import current_rules
         q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+        # these paths scale scores by head_dim^-0.5: fold in the rest
+        q_full = q_full * jnp.asarray(scale * q_full.shape[-1] ** 0.5, q_full.dtype)
         k_full = jnp.concatenate(
             [(ckv @ p["w_uk"]).reshape(b, s, h, m.qk_nope_head_dim),
              jnp.broadcast_to(kr[:, :, None, :], (b, s, h, m.qk_rope_head_dim))],
@@ -174,7 +201,7 @@ def mla_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         return o @ p["wo"], new_cache
 
     if (cache is not None) and (t > 2048 or t == 1):
-        # ABSORBED attention (§Perf-1): fold W_uk into the query and W_uv
+        # ABSORBED attention: fold W_uk into the query and W_uv
         # out of the value sum, so scores and the output accumulate against
         # the (B,S,r) latent directly — the per-head (B,S,H,*) K/V are never
         # materialized.  This is the arch's whole point at inference (the

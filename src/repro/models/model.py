@@ -198,6 +198,7 @@ _LEAF_AXES: dict[str, tuple] = {
     "w_down": ("ffn", "fsdp"),
     "router": (None, None),
     # MLA
+    "w_q": ("fsdp", "qheads"),
     "w_dq": ("fsdp", None), "w_uq": (None, "qheads"),
     "w_dkv": ("fsdp", None), "w_uk": (None, "qheads"),
     "w_uv": (None, "qheads"), "w_kr": (None, None),
@@ -231,7 +232,7 @@ _MOE_LEAF_AXES = {
 def _leaf_axes(path, leaf) -> tuple:
     names = [getattr(k, "key", None) for k in path]
     name = names[-1]
-    in_moe = "moe" in names
+    in_moe = "moe" in names and "shared" not in names
     table = _MOE_LEAF_AXES if (in_moe and name in _MOE_LEAF_AXES) else _LEAF_AXES
     axes = table.get(name)
     nd = len(leaf.shape)

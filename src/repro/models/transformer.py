@@ -5,15 +5,21 @@ Layers are grouped by the config's repeating ``pattern`` (e.g. gemma3's
 4 self + 1 cross); parameters of each pattern position are stacked over
 repeats and the stack is applied with ``lax.scan``, so HLO size (and
 compile time) is independent of depth.  The non-divisible remainder is
-unrolled.  ``jax.checkpoint`` (full remat) wraps the scanned body for
-training.
+unrolled, and so are the leading dense layers of an MoE config
+(``first_k_dense``, under ``"lead"``), which carry a dense MLP of width
+``d_ff`` in place of the experts.  ``jax.checkpoint`` (full remat) wraps
+the scanned body for training.
 
 Caches mirror the parameter structure: one stacked pytree per pattern
-position plus per-remainder-layer entries.
+position plus per-remainder-layer (and per-lead-layer) entries.  An MoE
+layer's cache also counts, per batch row, the routed (token, choice)
+pairs it has seen (``expert_choices``) and those that landed on an expert
+it holds (``held_expert_choices``), so the counts accumulate on the
+device with the cache and cost no transfer until the caller reads them.
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -22,8 +28,8 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..distributed.sharding import shard
 from .attention import attn_init, attention_block
-from .common import (Params, dense_init, layer_norm, layer_norm_init,
-                     rms_norm, rms_norm_init, split_keys)
+from .common import (Params, layer_norm, layer_norm_init, rms_norm,
+                     rms_norm_init, split_keys)
 from .mla import mla_block, mla_init
 from .mlp import mlp, mlp_init
 from .moe import moe_block, moe_init
@@ -92,6 +98,9 @@ def block_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_seq: int,
             kvd = (batch, s, cfg.num_kv_heads, cfg.head_dim)
             spec = {"k": jax.ShapeDtypeStruct(kvd, dtype),
                     "v": jax.ShapeDtypeStruct(kvd, dtype)}
+        if cfg.moe is not None:
+            spec["expert_choices"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+            spec["held_expert_choices"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
         if kind == "cross":
             n_kv = (cfg.vision.num_image_tokens if cfg.vision
                     else cfg.encoder.num_frames)
@@ -115,8 +124,7 @@ def _gather_fsdp(params: Params) -> Params:
     """Re-constrain FSDP-sharded weights to their TP-only sharding at the
     point of use: one small per-layer weight all-gather (ZeRO-3) instead of
     letting the partitioner psum (B,T,D)-sized activation partials, which
-    it otherwise prefers and which dominates the collective term
-    (EXPERIMENTS.md §Perf-2, iteration 4)."""
+    it otherwise prefers and which dominates the collective term."""
     from ..distributed.sharding import current_rules
     rules = current_rules()
     if rules is None or rules.rules.get("fsdp") is None:
@@ -141,7 +149,7 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, kind: str, *,
     # tried here and REMOVED: it never improved the collective term (the
     # partitioner already schedules the equivalent exchange), regressed
     # B=1 decode 48x, and ballooned vision-90B multi-pod train memory by
-    # forcing whole-stack gathers — full log in EXPERIMENTS.md §Perf-2.
+    # forcing whole-stack gathers.
 
     if kind == "rwkv":
         sub_cache = None if cache is None else \
@@ -200,7 +208,11 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, kind: str, *,
 
     h2 = _norm(cfg, p["ln2"], x)
     if cfg.moe is not None:
-        h2, aux = moe_block(p["moe"], h2, cfg)
+        h2, aux, held = moe_block(p["moe"], h2, cfg)
+        if cache is not None:
+            new_cache["expert_choices"] = (cache["expert_choices"]
+                                           + x.shape[1] * cfg.moe.top_k)
+            new_cache["held_expert_choices"] = cache["held_expert_choices"] + held
     else:
         h2 = mlp(p["mlp"], h2, cfg.act)
     if kind == "cross" and "gate_m" in p:
@@ -213,10 +225,19 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig, kind: str, *,
 # the scanned stack
 # ---------------------------------------------------------------------------
 
+def _layout(cfg: ModelConfig):
+    """(config of the leading dense layers, their count, scanned repeats of
+    the pattern, the remainder's kinds)."""
+    lead = cfg.first_k_dense
+    n = cfg.num_layers - lead
+    reps = n // len(cfg.pattern)
+    return (dataclasses.replace(cfg, moe=None), lead, reps,
+            list(cfg.pattern[:n - reps * len(cfg.pattern)]))
+
+
 def stack_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     pattern = cfg.pattern
-    reps = cfg.num_layers // len(pattern)
-    rem_kinds = cfg.layer_kinds()[reps * len(pattern):]
+    dense_cfg, lead, reps, rem_kinds = _layout(cfg)
     keys = split_keys(key, len(pattern) + len(rem_kinds))
 
     groups = []
@@ -226,22 +247,29 @@ def stack_init(key: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
         groups.append(stacked)
     remainder = [block_init(keys[len(pattern) + j], cfg, kind, dtype)
                  for j, kind in enumerate(rem_kinds)]
-    return {"groups": groups, "remainder": remainder}
+    out = {"groups": groups, "remainder": remainder}
+    if lead:
+        out["lead"] = [block_init(k, dense_cfg, "full", dtype)
+                       for k in split_keys(jax.random.fold_in(key, 1), lead)]
+    return out
 
 
 def stack_cache_specs(cfg: ModelConfig, batch: int, max_seq: int, dtype):
     pattern = cfg.pattern
-    reps = cfg.num_layers // len(pattern)
-    rem_kinds = cfg.layer_kinds()[reps * len(pattern):]
+    dense_cfg, lead, reps, rem_kinds = _layout(cfg)
 
     def stacked_spec(kind):
         spec = block_cache_spec(cfg, kind, batch, max_seq, dtype)
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((reps,) + s.shape, s.dtype), spec)
 
-    return {"groups": [stacked_spec(k) for k in pattern],
-            "remainder": [block_cache_spec(cfg, k, batch, max_seq, dtype)
-                          for k in rem_kinds]}
+    out = {"groups": [stacked_spec(k) for k in pattern],
+           "remainder": [block_cache_spec(cfg, k, batch, max_seq, dtype)
+                         for k in rem_kinds]}
+    if lead:
+        out["lead"] = [block_cache_spec(dense_cfg, "full", batch, max_seq, dtype)
+                       for _ in range(lead)]
+    return out
 
 
 def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -249,9 +277,16 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                 cross_x: Optional[jnp.ndarray] = None, causal: bool = True):
     """Returns (x, new_caches, total_aux)."""
     pattern = cfg.pattern
-    reps = cfg.num_layers // len(pattern)
-    rem_kinds = cfg.layer_kinds()[reps * len(pattern):]
+    dense_cfg, lead, reps, rem_kinds = _layout(cfg)
     aux_total = jnp.zeros((), jnp.float32)
+
+    new_lead = []
+    for j in range(lead):
+        c = None if caches is None else caches["lead"][j]
+        x, nc, _ = apply_block(params["lead"][j], x, dense_cfg, "full",
+                               pos_offset=pos_offset, cache=c,
+                               cross_x=cross_x, causal=causal)
+        new_lead.append(nc)
 
     def super_block(x, group_params, group_caches):
         """One pass through all pattern positions (one 'super layer')."""
@@ -303,4 +338,6 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     new_caches = None
     if caches is not None:
         new_caches = {"groups": list(new_group_caches), "remainder": new_rem}
+        if lead:
+            new_caches["lead"] = new_lead
     return x, new_caches, aux_total

@@ -104,6 +104,11 @@ class Timing:
     sample_s: float = 0.0               # hand-off from a dispatch to a fetch
     fetch_s: float = 0.0                # the blocking fetch of each token
     decode_steps: int = 0               # decode programs dispatched
+    # routed (token, choice) pairs over every MoE layer, prefill and
+    # decode, and those on an expert this replica holds; counted on the
+    # device in the cache and fetched once, after ``t_last``
+    expert_choices: int = 0
+    held_expert_choices: int = 0
 
 
 @dataclasses.dataclass
@@ -126,6 +131,23 @@ class ServeStats:
     @property
     def locality_fraction(self) -> float:
         return self.local / max(self.served, 1)
+
+
+EXPERT_COUNTERS = ("expert_choices", "held_expert_choices")
+
+
+def expert_counts(caches: Any) -> dict[str, int]:
+    """The MoE layers' counters of ``caches`` (batch row 0), summed over
+    the layers: one transfer, none for a model without experts."""
+    found: dict[str, list] = {k: [] for k in EXPERT_COUNTERS}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(caches):
+        name = getattr(path[-1], "key", None)
+        if name in found:
+            found[name].append(leaf[..., 0])
+    if not found["expert_choices"]:
+        return {k: 0 for k in EXPERT_COUNTERS}
+    host = jax.device_get(found)
+    return {k: int(sum(np.sum(a) for a in v)) for k, v in host.items()}
 
 
 def _greedy(logits: jax.Array) -> jax.Array:
@@ -200,6 +222,9 @@ class Replica:
             tm.t_last = last = fetched
             tok = nxt
         tm.dispatch_s, tm.sample_s, tm.fetch_s = dispatch, sample, fetch
+        counts = expert_counts(caches)
+        tm.expert_choices = counts["expert_choices"]
+        tm.held_expert_choices = counts["held_expert_choices"]
         return req
 
     def run_batch(self, reqs: list[Request]) -> list[Request]:

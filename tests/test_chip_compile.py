@@ -116,6 +116,34 @@ def test_qwen2_full_width_one_chip(one_chip, step):
     assert _bytes(compiled) <= HBM_BYTES
 
 
+@pytest.mark.parametrize("step", ["prefill", "decode_step"])
+def test_deepseek_v2_lite_share_one_chip(one_chip, step):
+    """One chip's expert-parallel share of DeepSeek-V2-Lite at published
+    widths (8 of 64 experts, a 1/8 vocabulary, 27 layers): the absorbed
+    chunked prefill of a 4096-token prompt and the absorbed decode step,
+    against an 8448-position latent cache."""
+    from bench import harness as H
+    from bench.drivers.serve_mla_moe import program_model
+    model = program_model(H.load_json(
+        H.ROOT / "bench" / "configs" / "deepseek-v2-lite-ep8.json"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(model.abstract_params())
+    caches = on_chip(jax.eval_shape(lambda: model.init_cache(1, 8448)))
+    if step == "prefill":
+        toks = on_chip(jax.ShapeDtypeStruct((1, 4096), jnp.int32))
+        lowered = greedy_prefill.lower(model, params, toks, caches)
+    else:
+        toks = on_chip(jax.ShapeDtypeStruct((1, 1), jnp.int32))
+        pos = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+        lowered = greedy_decode_step.lower(model, params, toks, pos, caches)
+    compiled = lowered.compile()
+    assert _bytes(compiled) <= HBM_BYTES
+
+
 @pytest.mark.parametrize("schedule", ["contiguous", "scattered"])
 def test_spmd_sweeps_four_chips(mesh4, schedule):
     cfg = JacobiGridConfig(ni=PAPER_SHAPE[0], nj=PAPER_SHAPE[1],

@@ -25,7 +25,7 @@ class TestDispatch:
         cfg = _cfg()
         p = moe_init(KEY, cfg)
         x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model))
-        out, aux = moe_block(p, x, cfg)
+        out, aux, _ = moe_block(p, x, cfg)
         assert out.shape == x.shape
         assert jnp.isfinite(out).all() and jnp.isfinite(aux)
 
@@ -36,7 +36,7 @@ class TestDispatch:
         m = cfg.moe
         p = moe_init(KEY, cfg)
         x = jax.random.normal(jax.random.key(1), (1, 8, cfg.d_model)) * 0.3
-        out, _ = moe_block(p, x, cfg)
+        out, _, _ = moe_block(p, x, cfg)
 
         logits = (x @ p["router"].astype(x.dtype)).astype(jnp.float32)
         gates = jax.nn.softmax(logits, -1)
@@ -60,7 +60,7 @@ class TestDispatch:
         cfg = _cfg(capacity_factor=0.01)
         p = moe_init(KEY, cfg)
         x = jax.random.normal(jax.random.key(1), (1, 64, cfg.d_model))
-        out, _ = moe_block(p, x, cfg)
+        out, _, _ = moe_block(p, x, cfg)
         norms = jnp.linalg.norm(out[0], axis=-1)
         assert (norms < 1e-6).any(), "expected dropped tokens with cap=1"
 
@@ -91,9 +91,9 @@ class TestDispatch:
         p2 = dict(p)
         p2["router"] = jnp.zeros_like(p["router"])
         x = jax.random.normal(jax.random.key(1), (2, 32, cfg.d_model))
-        _, aux_uniform = moe_block(p2, x, cfg)
+        _, aux_uniform, _ = moe_block(p2, x, cfg)
         # biased router: all mass on expert 0
         p3 = dict(p)
         p3["router"] = jnp.zeros_like(p["router"]).at[:, 0].set(20.0)
-        _, aux_biased = moe_block(p3, x, cfg)
+        _, aux_biased, _ = moe_block(p3, x, cfg)
         assert float(aux_biased) > float(aux_uniform)

@@ -212,7 +212,8 @@ def test_operator_line_reads_each_request_timeline(small_model):
         fields = dict(kv.split("=") for kv in line.replace(",", "").split()
                       if "=" in kv)
         assert set(fields) == {"replica", "queue_ms", "prefill_ms", "dispatch",
-                               "sample", "fetch"}
+                               "sample", "fetch", "expert_choices",
+                               "held_expert_choices"}
         assert all(float(v) >= 0 for v in fields.values())
 
 

@@ -52,8 +52,9 @@ def test_training_learns_synthetic_structure():
 
 def test_all_archs_registered():
     archs = list_archs()
-    assert len(archs) == 10
+    assert len(archs) == 11
     for required in ("qwen2-0.5b", "qwen2-1.5b", "minicpm3-4b", "gemma3-1b",
+                     "deepseek-v2-lite",
                      "qwen3-moe-30b-a3b", "phi3.5-moe-42b-a6.6b",
                      "recurrentgemma-9b", "whisper-base",
                      "llama-3.2-vision-90b", "rwkv6-3b"):
