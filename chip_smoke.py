@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro import spec  # noqa: E402
 from repro.core.tasks import PAPER_GRID  # noqa: E402
 from repro.kernels.jacobi.kernel import jacobi_sweep_pallas  # noqa: E402
-from repro.kernels.jacobi.ops import jacobi_sweep  # noqa: E402
+from repro.kernels.jacobi.ops import jacobi_sweep, stored_order  # noqa: E402
 from repro.kernels.jacobi.ref import jacobi_sweep_ref  # noqa: E402
 from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.launch.serve import MAX_SEQ, build, serve  # noqa: E402
@@ -73,23 +73,27 @@ def stencil_kernel(shape=PAPER_SHAPE, blocks=BLOCKS, sweeps: int = SWEEPS,
     """Phase 1: ``sweeps`` sweeps of the Pallas kernel, each checked against
     the jitted reference.  Returns the first sweep, on the host.
 
-    The kernel takes about 14.4 GB at the paper's grid (input, output and
-    two lane-padded copies), so nothing else lattice-sized may stay on the
-    device while it runs: the reference runs after it, and the first sweep
-    is kept on the host.
+    The kernel runs on the lattice in the order the device stores it
+    (i-minor on the TPU at the paper's grid), with the (di, dj) block over
+    the two stored-major axes.  At the paper's grid the input, the output
+    and the reference's output take 10.5 GB, so the first sweep is kept on
+    the host, not on the device.
     """
     di, dj = blocks
     x = _lattice(shape, seed)
+    order = stored_order(x)
     if not interpret:
-        txt = jacobi_sweep_pallas.lower(x, di=di, dj=dj).as_text()
+        txt = jacobi_sweep_pallas.lower(x, di=di, dj=dj,
+                                        order=order).as_text()
         _check("tpu_custom_call" in txt, "Jacobi kernel did not lower to "
                "a compiled TPU kernel")
     first = None
     for t in range(sweeps):
         y = jacobi_sweep(x, di=di, dj=dj, interpret=interpret)
-        y.block_until_ready()       # the kernel's copies are freed first
+        y.block_until_ready()
         err = float(_max_abs_err(y, jacobi_sweep_ref(x)))
-        print(f"  sweep {t}: shape={shape} blocks=({di},{dj},{shape[2]}) "
+        print(f"  sweep {t}: shape={shape} stored_order={order} "
+              f"blocks=({di},{dj},{shape[order[2]]}) "
               f"compiled={not interpret} max_abs_err={err!r}", flush=True)
         _check(err <= TOL, f"sweep {t}: kernel differs from reference by {err}")
         if first is None:

@@ -6,6 +6,7 @@ would refuse on the chip (illegal block shapes, programs that do not fit).
 The topology is described inside a fixture, never at import.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.core.tasks import PAPER_GRID
 from repro.kernels.jacobi.kernel import jacobi_sweep_pallas
+from repro.kernels.jacobi.ops import stored_order
 from repro.kernels.jacobi.ref import jacobi_sweep_ref
 from repro.launch.serve import MAX_SEQ
 from repro.models.model import build_model
@@ -76,7 +78,23 @@ def test_jacobi_kernel_refuses_paper_block(one_chip):
     # dj = 10 breaks the sublane rule: the reason the default block is 10x8
     x = jax.ShapeDtypeStruct(PAPER_SHAPE, jnp.float32, sharding=one_chip)
     with pytest.raises(Exception, match="divisible by 8"):
-        jacobi_sweep_pallas.lower(x, di=10, dj=10).compile()
+        jacobi_sweep_pallas.lower(x, di=10, dj=10,
+                                  order=stored_order(x)).compile()
+
+
+def test_jacobi_sweep_keeps_stored_layout(one_chip):
+    """The sweep as ``ops.jacobi_sweep`` runs it, on the lattice in the
+    order the chip stores it: no relayout copy of the lattice on either
+    side of the kernel, and no lattice-sized temporaries (two copies,
+    7,372,800,000 bytes, with the kernel pinned to row-major order)."""
+    x = jax.ShapeDtypeStruct(PAPER_SHAPE, jnp.float32, sharding=one_chip)
+    compiled = jacobi_sweep_pallas.lower(x, di=10, dj=8,
+                                         order=stored_order(x)).compile()
+    text = compiled.as_text()
+    assert not re.search(
+        r"f32\[(2400,600,600|600,600,2400)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert re.search(r"%jacobi_sweep_pallas\S* = \S+ custom-call\(", text)
 
 
 def test_reference_and_slab_update_paper_grid(one_chip):

@@ -1,10 +1,15 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode)."""
+import itertools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
+from repro.kernels.jacobi import ops as jacobi_ops
 from repro.kernels.jacobi.kernel import jacobi_sweep_pallas
 from repro.kernels.jacobi.ref import jacobi_sweep_ref
 from repro.kernels.rglru.kernel import rglru_scan_pallas
@@ -13,6 +18,11 @@ from repro.kernels.rwkv6.kernel import wkv6_pallas
 from repro.kernels.rwkv6.ref import wkv6_ref
 
 RNG = np.random.default_rng(0)
+
+
+# stored orders, major to minor: row-major, the TPU's i-minor order for
+# the paper's lattice, and j minor
+STORED_ORDERS = [(0, 1, 2), (1, 2, 0), (2, 0, 1)]
 
 
 class TestJacobi:
@@ -24,12 +34,56 @@ class TestJacobi:
         ((4, 4, 16), (2, 2)),
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32])
-    def test_matches_oracle(self, shape, block, dtype):
-        f = jnp.asarray(RNG.standard_normal(shape), dtype)
+    @pytest.mark.parametrize("order", STORED_ORDERS)
+    def test_matches_oracle(self, shape, block, dtype, order):
+        """``shape`` is the lattice as stored in ``order``: the block tiles
+        its first two axes."""
+        logical = tuple(shape[order.index(a)] for a in range(3))
+        f = jnp.asarray(RNG.standard_normal(logical), dtype)
         out = jacobi_sweep_pallas(f, 1 / 6, di=block[0], dj=block[1],
-                                  interpret=True)
+                                  interpret=True, order=order)
         ref = jacobi_sweep_ref(f, 1 / 6)
         np.testing.assert_allclose(out, ref, atol=1e-5)
+
+    @pytest.mark.parametrize("order", list(itertools.permutations(range(3))))
+    def test_orders_agree_bitwise(self, order):
+        """The six terms are added in logical order whatever the stored
+        order, so every order gives the row-major kernel's bits."""
+        f = jnp.asarray(RNG.standard_normal((8, 16, 24)), jnp.float32)
+        out = jacobi_sweep_pallas(f, di=4, dj=8, interpret=True, order=order)
+        base = jacobi_sweep_pallas(f, di=4, dj=8, interpret=True)
+        np.testing.assert_array_equal(out, base)
+
+    def test_ops_takes_identity_order_on_cpu(self, monkeypatch):
+        seen = []
+
+        def spy(*args, **kwargs):
+            seen.append(kwargs["order"])
+            return jacobi_sweep_pallas(*args, **kwargs)
+
+        monkeypatch.setattr(jacobi_ops, "jacobi_sweep_pallas", spy)
+        f = jnp.asarray(RNG.standard_normal((8, 16, 24)), jnp.float32)
+        out = jacobi_ops.jacobi_sweep(f, di=4, dj=8, interpret=True)
+        assert seen == [(0, 1, 2)]
+        np.testing.assert_allclose(out, jacobi_sweep_ref(f), atol=1e-5)
+
+    @pytest.mark.parametrize("kind", ["array", "shape_on_device", "shape",
+                                      "tracer"])
+    def test_stored_order_row_major_on_cpu(self, kind):
+        shape = (8, 16, 24)
+        if kind == "tracer":
+            seen = []
+            jax.jit(lambda x: seen.append(jacobi_ops.stored_order(x)))(
+                jnp.zeros(shape))
+            (order,) = seen
+        else:
+            on_device = SingleDeviceSharding(jax.devices()[0])
+            x = {"array": jnp.zeros(shape),
+                 "shape_on_device": jax.ShapeDtypeStruct(
+                     shape, jnp.float32, sharding=on_device),
+                 "shape": jax.ShapeDtypeStruct(shape, jnp.float32)}[kind]
+            order = jacobi_ops.stored_order(x)
+        assert order == (0, 1, 2)
 
     def test_c_coefficient(self):
         f = jnp.asarray(RNG.standard_normal((8, 8, 16)), jnp.float32)
