@@ -50,17 +50,6 @@ The driven workload is synthetic and arrival-paced (``num_domains`` tasks
 per scheduling round, 20% of them homed hot on domain 0 so the steal scan
 has real work), under a fixed batch-4 grab so all four hot paths fire.
 
-Fast vs slow (``fast_vs_slow`` in the JSON): the runtime keeps the
-pre-rewrite O(domains) victim scan and object-per-event log alive as a
-reference implementation (``Executor(fast=False)``).  For each configured
-scale this block drives the identical workload through both arms,
-**requires** bit-identical results — same ``RuntimeStats`` snapshot, same
-whole-run event counts, and byte-identical event-window CSV — and reports
-each arm's ns/decision plus the fast/slow speedup per hot path
-(``speedup_*``; the committed artifact is where the ≥2x steal_scan /
-event_append acceptance number lives, and ``FVS_SPEEDUP_FLOOR`` guards
-against the fast path silently regressing toward the slow one).
-
 CSV: n_tasks,num_domains,submit_route_ns,steal_scan_ns,batch_grab_ns,
 event_append_ns,wall_off_s,wall_on_s,overhead_frac,tasks_per_s
 
@@ -90,14 +79,6 @@ MILLION_REPEATS = 2            # repeat floor for the 10^6-task rows
 BATCH_SIZE = 4                 # fixed batch so batch_grab fires
 STEAL_PENALTY = 4.0
 HOT_EVERY = 5                  # every 5th task homed on domain 0
-DEPTH_STRIDE_HUGE = 64         # depth-sample stride for the 10^6-task rows
-
-# fast-vs-slow equivalence + speedup scales, (n_tasks, num_domains)
-FVS_SCALES = ((100_000, 4), (100_000, 16))
-FAST_FVS_SCALES = ((20_000, 4),)
-FVS_SPEEDUP_FLOOR = 1.5        # fast arm must beat slow by at least this
-FVS_GATED_PATHS = ("steal_scan", "event_append")
-FVS_GATE_MIN_TASKS = 100_000   # speedup floor binds at this scale and up
 
 
 def _spec(num_domains: int, *, obs_enabled: bool, profile: bool):
@@ -112,20 +93,12 @@ def _spec(num_domains: int, *, obs_enabled: bool, profile: bool):
     )
 
 
-def _drive(ex, n_tasks: int, num_domains: int, *,
-           contended: bool = False) -> float:
+def _drive(ex, n_tasks: int, num_domains: int) -> float:
     """Submit ``num_domains`` tasks per scheduling round (20% homed hot on
     domain 0), step between waves, drain; returns elapsed wall seconds.
     Takes a bare ``Executor`` (spec callers pass ``built.executor``).  The
     big scales overflow the event ring buffer by design — the one-shot
-    warning is expected and muted here (storm analysis is not run).
-
-    ``contended=True`` homes *every* task on domain 0: all other workers'
-    local queues stay dry, so each of their grabs runs the victim-selection
-    scan or the machine-wide-empty poll — the code the fast eligibility
-    structures replace.  The default mix is local-pop dominated (every
-    timed dequeue is a successful pop) and measures the other half of the
-    hot path."""
+    warning is expected and muted here (storm analysis is not run)."""
     # GC hygiene: a collection pause landing inside one arm but not the
     # other would swamp the few-percent delta the gate watches.  The driven
     # structures are cycle-free (refcounting reclaims them), so the cyclic
@@ -137,8 +110,7 @@ def _drive(ex, n_tasks: int, num_domains: int, *,
             warnings.simplefilter("ignore", RuntimeWarning)
             t0 = time.perf_counter()
             for i in range(n_tasks):
-                home = (0 if contended or i % HOT_EVERY == 0
-                        else i % num_domains)
+                home = 0 if i % HOT_EVERY == 0 else i % num_domains
                 ex.submit(ex.make_task(home=home))
                 if i % num_domains == num_domains - 1:
                     ex.step()
@@ -211,92 +183,9 @@ def measure(n_tasks: int, num_domains: int,
     }
 
 
-def _fvs_executor(num_domains: int, *, fast: bool):
-    from repro.obs import HotPathProfiler
-    from repro.runtime import Executor
-
-    prof = HotPathProfiler()
-    ex = Executor(num_domains,
-                  steal_order="cyclic",
-                  steal_penalty=lambda t, w: STEAL_PENALTY,
-                  batch=BATCH_SIZE,
-                  profiler=prof,
-                  fast=fast,
-                  depth_sample_stride=DEPTH_STRIDE_HUGE)
-    return ex, prof
-
-
-def measure_fast_vs_slow(n_tasks: int, num_domains: int, *,
-                         gates: bool = True) -> tuple[dict, list[str]]:
-    """Fast-path vs reference-path A/B at one scale: equivalence + speedup.
-
-    Drives the identical *contended* workload (every task homed on domain
-    0 — see ``_drive``) through ``Executor(fast=True)`` and
-    ``Executor(fast=False)`` (the pre-rewrite O(domains) victim scan and
-    object-per-event ``ReferenceEventLog``), both profiled.  Contention
-    makes every non-hot worker's grab a victim scan or an empty poll —
-    the code the rewrite replaces — while the main ladder's mixed drive
-    covers the local-pop path.  Equivalence is
-    **mandatory** regardless of ``gates`` — identical ``RuntimeStats``,
-    identical whole-run event counts, and byte-identical retained-window
-    event CSV — because bit-identity is the fast path's contract, not a
-    performance target.  The speedup floor (``FVS_SPEEDUP_FLOOR`` on
-    ``FVS_GATED_PATHS`` at >= ``FVS_GATE_MIN_TASKS`` tasks) is soft
-    anti-regression insurance; the headline ≥2x acceptance numbers live in
-    the committed full-ladder artifact.
-    """
-    snaps = {}
-    for fast in (True, False):
-        ex, prof = _fvs_executor(num_domains, fast=fast)
-        _drive(ex, n_tasks, num_domains, contended=True)
-        snaps[fast] = {
-            "stats": ex.metrics.snapshot(),
-            "counts": ex.events.counts(),
-            "csv": tuple(ex.events.to_csv_lines()),
-            "events_retained": len(ex.events),
-            "events_total": ex.events.total,
-            "prof": prof.snapshot(),
-        }
-    f, s = snaps[True], snaps[False]
-    for key, label in (("stats", "RuntimeStats"),
-                       ("counts", "event counts"),
-                       ("csv", "event CSV")):
-        if f[key] != s[key]:
-            raise SystemExit(
-                f"fast/slow divergence at n_tasks={n_tasks}, "
-                f"num_domains={num_domains}: {label} differ — "
-                f"fast={f[key]!r:.200} slow={s[key]!r:.200}")
-    ns_f, ns_s = f["prof"]["ns_per_call"], s["prof"]["ns_per_call"]
-    row = {
-        "n_tasks": n_tasks,
-        "num_domains": num_domains,
-        "ns_per_decision": {
-            **{f"{p}_fast": ns_f[p] for p in sorted(ns_f)},
-            **{f"{p}_slow": ns_s[p] for p in sorted(ns_s)},
-        },
-        "stats_identical": True,
-        "events_identical": True,
-        "events_compared": f["events_retained"],
-        "events_total": f["events_total"],
-    }
-    failures = []
-    for p in sorted(ns_f):
-        if ns_f[p] > 0:
-            speedup = ns_s[p] / ns_f[p]
-            row[f"speedup_{p}"] = speedup
-            if (gates and n_tasks >= FVS_GATE_MIN_TASKS
-                    and p in FVS_GATED_PATHS
-                    and speedup < FVS_SPEEDUP_FLOOR):
-                failures.append(
-                    f"n_tasks={n_tasks} num_domains={num_domains}: "
-                    f"{p} fast/slow speedup {speedup:.2f}x "
-                    f"< floor {FVS_SPEEDUP_FLOOR}x")
-    return row, failures
-
-
 def main(task_scales=TASK_SCALES, domain_scales=DOMAIN_SCALES,
          repeats: int = 5, json_path: str | None = None,
-         gates: bool = True, fvs_scales=FVS_SCALES) -> list[str]:
+         gates: bool = True) -> list[str]:
     lines = ["n_tasks,num_domains,submit_route_ns,steal_scan_ns,"
              "batch_grab_ns,event_append_ns,wall_off_s,wall_on_s,"
              "overhead_frac,tasks_per_s"]
@@ -322,22 +211,12 @@ def main(task_scales=TASK_SCALES, domain_scales=DOMAIN_SCALES,
                     f"n_tasks={n_tasks} num_domains={num_domains}: obs-on "
                     f"cost {row['overhead_frac']:+.1%} wall time "
                     f"(gate < {OVERHEAD_GATE:.0%})")
-    fvs_rows = []
-    for n_tasks, num_domains in fvs_scales:
-        row, fvs_fails = measure_fast_vs_slow(n_tasks, num_domains,
-                                              gates=gates)
-        fvs_rows.append(row)
-        failures.extend(fvs_fails)
-        lines.append(
-            f"# fast_vs_slow n_tasks={n_tasks} num_domains={num_domains}: "
-            + " ".join(f"{p}={row.get(f'speedup_{p}', 0.0):.2f}x"
-                       for p in FVS_GATED_PATHS))
     if json_path:
         with open(json_path, "w", encoding="utf-8") as fh:
             json.dump({"bench": "scheduler_overhead",
                        "overhead_gate": OVERHEAD_GATE,
                        "batch_size": BATCH_SIZE, "repeats": repeats,
-                       "results": rows, "fast_vs_slow": fvs_rows},
+                       "results": rows},
                       fh, indent=2)
             fh.write("\n")
     if failures:
@@ -350,7 +229,6 @@ if __name__ == "__main__":
     fast = "--fast" in sys.argv
     out = main(task_scales=FAST_TASK_SCALES if fast else TASK_SCALES,
                domain_scales=FAST_DOMAIN_SCALES if fast else DOMAIN_SCALES,
-               fvs_scales=FAST_FVS_SCALES if fast else FVS_SCALES,
                json_path="BENCH_overhead.json")
     for ln in out:
         print(ln)

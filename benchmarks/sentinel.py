@@ -25,10 +25,7 @@ Tolerance policy (``metric_policy``) — the load-bearing design choice:
     catch an accidental O(n) slip in a hot path.  Pure environment
     readouts (``wall_*``, ``tasks_per_s``, ``overhead_frac`` — already
     gated inside the benchmark itself, ``repeats_used``,
-    ``profile_total_ns``) are reported but never gated here, as are the
-    ``speedup_*`` ratios of the fast-vs-slow block (two wall readouts in a
-    ratio; equivalence and the speedup floor already gate inside the
-    benchmark).
+    ``profile_total_ns``) are reported but never gated here.
   * metrics present in the baseline but missing fresh fail (a deleted
     measurement is a regression of the record); new fresh metrics are
     reported as ``new`` and pass (the next baseline refresh adopts them).
@@ -110,7 +107,7 @@ def metric_policy(bench: str, path: str) -> str:
     """``"equal"`` (deterministic — exact), ``"lower"`` (wall, loose
     lower-is-better), or ``"info"`` (reported, never gated)."""
     leaf = path.rsplit(".", 1)[-1].split("[")[0]
-    if leaf in _UNGATED or leaf.startswith("speedup_"):
+    if leaf in _UNGATED:
         return "info"
     if bench == "overhead" and ".ns_per_decision." in f".{path}":
         return "lower"
@@ -165,11 +162,10 @@ def _run_topology(base: dict, out: str) -> None:
 def _overhead_rows(base: dict, out: str, full: bool) -> None:
     from benchmarks import scheduler_overhead as so
     if full:
-        scales, domains, fvs = so.TASK_SCALES, so.DOMAIN_SCALES, so.FVS_SCALES
+        scales, domains = so.TASK_SCALES, so.DOMAIN_SCALES
     else:
-        scales, domains, fvs = (so.FAST_TASK_SCALES, so.FAST_DOMAIN_SCALES,
-                                so.FAST_FVS_SCALES)
-    so.main(task_scales=scales, domain_scales=domains, fvs_scales=fvs,
+        scales, domains = so.FAST_TASK_SCALES, so.FAST_DOMAIN_SCALES
+    so.main(task_scales=scales, domain_scales=domains,
             repeats=base.get("repeats", 5), json_path=out)
 
 
@@ -183,17 +179,15 @@ def _intersect_overhead(base: dict, fresh: dict) -> tuple[dict, dict]:
     """Restrict both overhead results to the shared (n_tasks, num_domains)
     rows, re-keyed by configuration so row order can't misalign the diff
     (the fast CI ladder runs a subset of the committed full ladder)."""
-    def rows(d, key):
+    def rows(d):
         return {f"{r['n_tasks']}x{r['num_domains']}": r
-                for r in d.get(key, [])}
-    strip = ("results", "fast_vs_slow")
-    nb = {k: v for k, v in base.items() if k not in strip}
-    nf = {k: v for k, v in fresh.items() if k not in strip}
-    for key, dest in (("results", "rows"), ("fast_vs_slow", "fvs")):
-        rb, rf = rows(base, key), rows(fresh, key)
-        shared = sorted(set(rb) & set(rf))
-        nb[dest] = {k: rb[k] for k in shared}
-        nf[dest] = {k: rf[k] for k in shared}
+                for r in d.get("results", [])}
+    nb = {k: v for k, v in base.items() if k != "results"}
+    nf = {k: v for k, v in fresh.items() if k != "results"}
+    rb, rf = rows(base), rows(fresh)
+    shared = sorted(set(rb) & set(rf))
+    nb["rows"] = {k: rb[k] for k in shared}
+    nf["rows"] = {k: rf[k] for k in shared}
     return nb, nf
 
 

@@ -1,7 +1,7 @@
 """repro.core — the paper's contribution: locality-queue task scheduling.
 
 Faithful layer (drives the discrete-event simulator, reproduces Fig. 3/4):
-  topology, tasks, placement, queues, scheduler, cost_model, simulator.
+  topology, tasks, placement, scheduler, cost_model, simulator.
 
 SPMD layer (the technique adapted to ahead-of-time TPU scheduling):
   assignment.
@@ -9,7 +9,6 @@ SPMD layer (the technique adapted to ahead-of-time TPU scheduling):
 from .assignment import Assignment, build_assignment, round_robin_assignment
 from .cost_model import maxmin_rates, stream_sanity
 from .placement import place
-from .queues import LocalityQueues
 from .scheduler import (
     OpenMPLocalityQueues,
     OpenMPTasking,
@@ -33,7 +32,7 @@ from .topology import (
 
 __all__ = [
     "Assignment", "build_assignment", "round_robin_assignment",
-    "maxmin_rates", "stream_sanity", "place", "LocalityQueues",
+    "maxmin_rates", "stream_sanity", "place",
     "OpenMPLocalityQueues", "OpenMPTasking", "Policy", "StaticWorksharing",
     "TBBLocalityQueues", "TBBParallelFor", "tbb_first_touch",
     "SimParams", "SimResult", "run_samples", "simulate", "summarize",

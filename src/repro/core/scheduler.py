@@ -49,6 +49,15 @@ class PopResult:
     stolen: bool = False
 
 
+def queue_domain(blk: int, home: int, num_domains: int) -> int:
+    """The locality queue a block is sorted into: its home domain, or, for
+    home -1 (pages interleaved over every domain, ``round_robin``
+    placement), domain ``blk % num_domains``.  An interleaved block is
+    equally near every domain, so no queue is preferred, and cycling by
+    block id spreads the queued work the way the pages are spread."""
+    return blk % num_domains if home == -1 else home
+
+
 class Policy:
     """Base class; see module docstring for the contract."""
 
@@ -155,7 +164,8 @@ class OpenMPLocalityQueues(Policy):
 
     def submit_one(self):
         blk = self._pending.popleft()
-        self._queues.enqueue(blk, int(self._homes[blk]))
+        self._queues.enqueue(blk, queue_domain(blk, int(self._homes[blk]),
+                                               self._queues.num_domains))
 
     def pop(self, thread):
         got = self._queues.dequeue(int(self._thread_ld[thread]))
@@ -215,7 +225,9 @@ class TBBLocalityQueues(Policy):
         self._queues = DomainQueues(topo.num_domains, steal_order="cyclic")
         order = rng.permutation(grid.num_blocks)   # uncontrolled availability
         for blk in order:
-            self._queues.enqueue(int(blk), int(homes[blk]))
+            blk = int(blk)
+            self._queues.enqueue(blk, queue_domain(blk, int(homes[blk]),
+                                                   topo.num_domains))
         self._thread_ld = thread_ld
 
     def pop(self, thread):

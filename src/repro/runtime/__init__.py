@@ -48,7 +48,7 @@ Usage::
           ex.stats.steal_penalty)
 """
 from .adaptive import AdaptiveSteal, GreedySteal, NoSteal, StealGovernor
-from .events import Event, EventLog, ReferenceEventLog
+from .events import Event, EventLog
 from .executor import Executor, Task
 from .metrics import MetricsRecorder, RuntimeStats
 from .queues import DomainQueues, Popped, SubmissionPool
@@ -56,7 +56,7 @@ from .workers import Worker, WorkerPool, WorkerStats
 
 __all__ = [
     "AdaptiveSteal", "GreedySteal", "NoSteal", "StealGovernor",
-    "Event", "EventLog", "ReferenceEventLog",
+    "Event", "EventLog",
     "Executor", "Task",
     "MetricsRecorder", "RuntimeStats",
     "DomainQueues", "Popped", "SubmissionPool",

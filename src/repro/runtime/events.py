@@ -29,16 +29,14 @@ pays dtype coercion (~5x a list store — measured, and ``emit`` is nothing
 ``columns()``, which exports the retained window as one typed numpy array
 per field for vectorized analytics.  ``Event`` objects are materialized
 lazily, only when the log is iterated / exported; readers see the exact
-same frozen dataclass as before.  ``ReferenceEventLog`` keeps the original
-object-per-event implementation as the executable specification the
-columnar ring is equivalence-tested against
-(``benchmarks.scheduler_overhead`` fast_vs_slow).
+same frozen dataclass as before.  The tests hold the columnar ring to a
+plain object-per-event log (``tests/runtime_reference.py``): same events,
+counts and CSV export.
 """
 from __future__ import annotations
 
 import dataclasses
 import warnings
-from collections import Counter, deque
 from typing import Iterator
 
 import numpy as np
@@ -232,56 +230,3 @@ class EventLog:
                 f"{e.src_domain},{e.cost:g},{e.penalty:g}" for e in self]
         return out
 
-
-class ReferenceEventLog:
-    """The pre-columnar object-per-event ring: one frozen ``Event`` built
-    per emit into a ``deque``.  Kept as the executable specification —
-    ``benchmarks.scheduler_overhead``'s fast_vs_slow block and the runtime
-    tests hold ``EventLog`` to producing the identical event sequence,
-    counts, and CSV export."""
-
-    def __init__(self, maxlen: int = 65536):
-        maxlen = _check_maxlen(maxlen)
-        self.maxlen = maxlen
-        self._buf: deque[Event] = deque(maxlen=maxlen)
-        self._counts: Counter[str] = Counter()
-        self._warned_overflow = False
-
-    def emit(self, step: int, kind: str, worker: int, domain: int,
-             task_uid: int, src_domain: int = -1, cost: float = 0.0,
-             penalty: float = 0.0) -> None:
-        if not self._warned_overflow and len(self._buf) == self.maxlen:
-            self._warned_overflow = True
-            warnings.warn(_OVERFLOW_MSG.format(maxlen=self.maxlen),
-                          RuntimeWarning, stacklevel=2)
-        self._buf.append(Event(step, kind, worker, domain, task_uid,
-                               src_domain, cost, penalty))
-        self._counts[kind] += 1
-
-    def counts(self) -> dict[str, int]:
-        return dict(self._counts)
-
-    @property
-    def total(self) -> int:
-        return sum(self._counts.values())
-
-    @property
-    def dropped(self) -> int:
-        return self.total - len(self._buf)
-
-    def tail(self, n: int = 50) -> list[Event]:
-        return list(self._buf)[-n:]
-
-    def __len__(self) -> int:
-        return len(self._buf)
-
-    def __iter__(self) -> Iterator[Event]:
-        return iter(self._buf)
-
-    def to_csv_lines(self) -> list[str]:
-        out = [f"# events total={self.total} retained={len(self._buf)} "
-               f"dropped={self.dropped} window={self.maxlen}",
-               "step,kind,worker,domain,task_uid,src_domain,cost,penalty"]
-        out += [f"{e.step},{e.kind},{e.worker},{e.domain},{e.task_uid},"
-                f"{e.src_domain},{e.cost:g},{e.penalty:g}" for e in self._buf]
-        return out

@@ -22,7 +22,7 @@ from typing import Any, Callable, Optional, Sequence
 import numpy as np
 
 from .adaptive import GreedySteal, StealGovernor
-from .events import EventLog, ReferenceEventLog
+from .events import EventLog
 from .metrics import MetricsRecorder
 from .queues import DomainQueues
 from .workers import Worker, WorkerPool
@@ -127,22 +127,6 @@ class Executor:
                         a decision), so profiled runs keep bit-identical
                         ``RuntimeStats`` and replays; with the default
                         ``None`` the timers are skipped entirely.
-    fast:               selects the hot-path implementation.  ``True`` (the
-                        default) uses the incremental eligibility structures
-                        in ``DomainQueues`` and the columnar ``EventLog``;
-                        ``False`` runs the pre-rewrite reference scan and
-                        the object-per-event ``ReferenceEventLog``.  The two
-                        are bit-identical (same stats, same event sequence,
-                        same RNG draws) — the slow arm exists as the
-                        executable specification for the
-                        ``benchmarks.scheduler_overhead`` fast_vs_slow
-                        equivalence gate.
-    depth_sample_stride: record the per-domain queue-depth sample every
-                        N-th scheduling round (default 1 = every round, the
-                        original behaviour).  Depth sampling is O(domains)
-                        per round; million-task benchmark drives raise the
-                        stride to keep it off the hot path.  Counters in
-                        ``RuntimeStats`` are unaffected.
     """
 
     def __init__(self, num_domains: int,
@@ -161,22 +145,18 @@ class Executor:
                  batch_handler: BatchHandler | None = None,
                  step_hook: StepHook | None = None,
                  topology: Any = None,
-                 profiler: Any = None,
-                 fast: bool = True,
-                 depth_sample_stride: int = 1):
+                 profiler: Any = None):
         self.num_domains = num_domains
         self.seed = seed
         self.rng = np.random.default_rng(seed)
         self.topology = topology
-        self.fast = fast
         # hoisted out of the per-dequeue steal_scan region: tier count when
         # hierarchical (per-level governor thresholds apply), else 0
         self._hier_levels = (topology.num_levels
                             if topology is not None and topology.hierarchical
                             else 0)
         self.queues = DomainQueues(num_domains, steal_order=steal_order,
-                                   rng=self.rng, topology=topology,
-                                   fast=fast)
+                                   rng=self.rng, topology=topology)
         if worker_domains is None:
             worker_domains = list(range(num_domains))
         self.pool = WorkerPool(worker_domains)
@@ -187,9 +167,8 @@ class Executor:
         self.pool_cap = pool_cap
         self.governor = governor or GreedySteal()
         self.steal_penalty = steal_penalty
-        self.metrics = MetricsRecorder(depth_stride=depth_sample_stride)
-        log_cls = EventLog if fast else ReferenceEventLog
-        self.events = log_cls(event_maxlen) if record_events else None
+        self.metrics = MetricsRecorder()
+        self.events = EventLog(event_maxlen) if record_events else None
         self.submit_hook = submit_hook
         self.router = router
         self.batch = batch
@@ -293,8 +272,7 @@ class Executor:
         operation."""
         self._step += 1
         n = sum(self._attempt(w) for w in self.pool)
-        if self.metrics.wants_depths(self._step):
-            self.metrics.sample_depths(self._step, self.queues.queue_sizes())
+        self.metrics.sample_depths(self._step, self.queues.queue_sizes())
         if self.step_hook is not None:
             self.step_hook(self)
         return n

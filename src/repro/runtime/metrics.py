@@ -43,11 +43,8 @@ class RuntimeStats:
 class MetricsRecorder:
     """Counters plus a bounded time series of per-domain queue depths."""
 
-    def __init__(self, depth_window: int = 4096, depth_stride: int = 1):
-        if depth_stride < 1:
-            raise ValueError(f"depth_stride must be >= 1, got {depth_stride}")
+    def __init__(self, depth_window: int = 4096):
         self.stats = RuntimeStats()
-        self.depth_stride = depth_stride
         self._depths: deque[tuple[int, tuple[int, ...]]] = deque(maxlen=depth_window)
 
     # -- hooks called by the executor --------------------------------------
@@ -70,12 +67,6 @@ class MetricsRecorder:
 
     def on_idle(self) -> None:
         self.stats.idle_polls += 1
-
-    def wants_depths(self, step: int) -> bool:
-        """Whether ``step`` falls on the depth-sampling stride.  The
-        executor consults this before building the O(domains) size list, so
-        a stride > 1 skips the sampling cost, not just the storage."""
-        return step % self.depth_stride == 0
 
     def sample_depths(self, step: int, sizes: list[int]) -> None:
         self._depths.append((step, tuple(sizes)))

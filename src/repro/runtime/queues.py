@@ -48,24 +48,11 @@ cost returns to exactly 0.0 whenever the snapshot arithmetic is exact
 costs can leave a ±ulp residue, which is the accounting being honest, not
 drifting.
 
-Victim selection has two implementations, selected by the ``fast`` flag:
-
-  ``fast=True``  (default) — incrementally-maintained eligibility
-      structures: a nonempty-domain bitmask (empty↔nonempty transitions
-      are one ``|=``/``&=``; the cyclic successor is two's-complement bit
-      arithmetic — O(d/64) word ops in C, no Python loop), lazy max-heaps
-      keyed on depth / queued cost (``longest`` / ``cost_weighted``
-      selection is amortized O(log d)), and per-level nonempty-peer
-      counters that let the hierarchical scan skip whole tiers in O(1).
-  ``fast=False`` — the pre-rewrite O(domains) linear scans, kept verbatim
-      as the executable specification.
-
-The two paths are **bit-identical**: same victim, same visit order, and
-the same RNG draw sequence (``random`` draws once over the identical
-ascending eligible list, and draws nothing when no victim is eligible).
-``benchmarks.scheduler_overhead``'s ``fast_vs_slow`` block and the
-hypothesis property in ``tests/test_runtime.py`` hold the paths to that
-contract.
+Victim selection runs on incrementally maintained structures (a
+nonempty-domain bitmask, lazy max-heaps keyed on depth or queued cost,
+per-tier nonempty-peer counters), and the tests hold it to a plain
+O(domains) linear-scan reference (``tests/runtime_reference.py``): same
+victim, same visit order, same RNG draws.
 
 ``SubmissionPool`` captures the other half of the paper's machinery: the
 bounded FIFO pool of submitted-but-unconsumed tasks of OpenMP tasking
@@ -116,7 +103,7 @@ class DomainQueues:
 
     def __init__(self, num_domains: int, steal_order: str = "cyclic",
                  rng: np.random.Generator | None = None,
-                 topology=None, fast: bool = True):
+                 topology=None):
         if num_domains < 1:
             raise ValueError("need at least one domain")
         if steal_order not in self.STEAL_ORDERS:
@@ -131,7 +118,6 @@ class DomainQueues:
         self.num_domains = num_domains
         self.steal_order = steal_order
         self.topology = topology
-        self.fast = fast
         self._rng = rng
         # each slot is an (item, cost) pair: the enqueue-time cost snapshot
         # travels with the item (the drift fix), and the fused layout costs
@@ -140,16 +126,15 @@ class DomainQueues:
             deque() for _ in range(num_domains)]
         self._costs: list[float] = [0.0] * num_domains
         self._size = 0
-        # -- fast-path eligibility structures ------------------------------
+        # -- eligibility structures -----------------------------------------
         # (queue depth itself is never duplicated: ``len(deque)`` is O(1),
         # so a shadow depth array would be pure per-pop maintenance cost)
-        self._hier = (fast and topology is not None and topology.hierarchical)
+        self._hier = topology is not None and topology.hierarchical
         self._ne_mask = 0                # bit d set <=> domain d nonempty
         # lazy max-heap of (-depth, d) / (-cost, d); entries go stale when
         # the domain's state moves on and are discarded at query time
         self._order_heap: Optional[list[tuple[float, int]]] = None
-        if fast and not self._hier and steal_order in ("longest",
-                                                       "cost_weighted"):
+        if not self._hier and steal_order in ("longest", "cost_weighted"):
             self._order_heap = []
         self._heap_limit = max(64, 8 * num_domains)
         # per-level nonempty-peer counters: _lvl_nonempty[a][lv-1] counts
@@ -160,11 +145,7 @@ class DomainQueues:
             self._lvl_nonempty = [[0] * topology.num_levels
                                   for _ in range(num_domains)]
 
-    @staticmethod
-    def _item_cost(item: Any) -> float:
-        return float(getattr(item, "cost", 1.0))
-
-    # -- fast-path maintenance ---------------------------------------------
+    # -- eligibility maintenance ---------------------------------------------
     def _heap_push(self, key: float, domain: int) -> None:
         heap = self._order_heap
         if len(heap) >= self._heap_limit:
@@ -194,7 +175,7 @@ class DomainQueues:
 
     def _lvl_shift(self, domain: int, delta: int) -> None:
         """Shift every peer's nonempty-at-tier counter when ``domain``
-        crosses the empty↔nonempty boundary (hierarchical fast path)."""
+        crosses the empty↔nonempty boundary (hierarchical machines only)."""
         lvl = self._lvl_nonempty
         topo = self.topology
         for a in range(self.num_domains):
@@ -203,21 +184,23 @@ class DomainQueues:
 
     # -- producer side -----------------------------------------------------
     def enqueue(self, item: Any, domain: int) -> None:
+        if not 0 <= domain < self.num_domains:
+            raise ValueError(f"domain {domain} outside the queues' "
+                             f"{self.num_domains} domains")
         cost = float(getattr(item, "cost", 1.0))   # snapshot at enqueue
         q = self._queues[domain]
         q.append((item, cost))
         self._costs[domain] += cost
         self._size += 1
-        if self.fast:
-            if len(q) == 1:
-                self._ne_mask |= 1 << domain
-                if self._lvl_nonempty is not None:
-                    self._lvl_shift(domain, 1)
-            if self._order_heap is not None:
-                if self.steal_order == "longest":
-                    self._heap_push(-len(q), domain)
-                else:
-                    self._heap_push(-self._costs[domain], domain)
+        if len(q) == 1:
+            self._ne_mask |= 1 << domain
+            if self._lvl_nonempty is not None:
+                self._lvl_shift(domain, 1)
+        if self._order_heap is not None:
+            if self.steal_order == "longest":
+                self._heap_push(-len(q), domain)
+            else:
+                self._heap_push(-self._costs[domain], domain)
 
     # -- consumer side -----------------------------------------------------
     def dequeue(self, domain: int, allow_steal: bool = True,
@@ -239,22 +222,21 @@ class DomainQueues:
             item, cost = q.popleft()
             self._costs[domain] -= cost
             self._size -= 1
-            if self.fast:
-                if not q:
-                    self._ne_mask &= ~(1 << domain)
-                    if self._lvl_nonempty is not None:
-                        self._lvl_shift(domain, -1)
-                elif self._order_heap is not None:
-                    if self.steal_order == "longest":
-                        self._heap_push(-len(q), domain)
-                    else:
-                        self._heap_push(-self._costs[domain], domain)
+            if not q:
+                self._ne_mask &= ~(1 << domain)
+                if self._lvl_nonempty is not None:
+                    self._lvl_shift(domain, -1)
+            elif self._order_heap is not None:
+                if self.steal_order == "longest":
+                    self._heap_push(-len(q), domain)
+                else:
+                    self._heap_push(-self._costs[domain], domain)
             # C-level tuple.__new__: the namedtuple's keyword/default
             # __new__ costs ~200ns more per executed task
             return _tuple_new(Popped, (item, domain, False, 0, 0.0))
         if not allow_steal:
             return None
-        if self.fast and not self._ne_mask:
+        if not self._ne_mask:
             return None     # machine-wide empty: no victim anywhere
         victim = self._pick_victim(domain, min_victim)
         if victim is None:
@@ -276,16 +258,15 @@ class DomainQueues:
         item, cost = q.popleft()
         self._costs[domain] -= cost
         self._size -= 1
-        if self.fast:
-            if not q:
-                self._ne_mask &= ~(1 << domain)
-                if self._lvl_nonempty is not None:
-                    self._lvl_shift(domain, -1)
-            elif self._order_heap is not None:
-                if self.steal_order == "longest":
-                    self._heap_push(-len(q), domain)
-                else:
-                    self._heap_push(-self._costs[domain], domain)
+        if not q:
+            self._ne_mask &= ~(1 << domain)
+            if self._lvl_nonempty is not None:
+                self._lvl_shift(domain, -1)
+        elif self._order_heap is not None:
+            if self.steal_order == "longest":
+                self._heap_push(-len(q), domain)
+            else:
+                self._heap_push(-self._costs[domain], domain)
         return item
 
     def drain(self, domain: int, n: int, budget: Optional[float] = None,
@@ -336,39 +317,17 @@ class DomainQueues:
         if mv is None:
             return None
         mv = max(mv, 1)
-        if self.fast:
-            return self._pick_victim_flat_fast(domain, mv)
-        return self._pick_victim_flat_reference(domain, mv)
+        return self._pick_victim_flat(domain, mv)
 
-    # -- reference (pre-rewrite) scans --------------------------------------
-    def _pick_victim_flat_reference(self, domain: int,
-                                    mv: int) -> Optional[int]:
-        """The original single-tier O(domains) scan, kept verbatim: the
-        executable specification the fast path is equivalence-gated
-        against — same visit order and the same RNG draw sequence."""
-        if self.steal_order == "cyclic":
-            for off in range(1, self.num_domains):
-                d = (domain + off) % self.num_domains
-                if len(self._queues[d]) >= mv:
-                    return d
-            return None
-        eligible = [d for d in range(self.num_domains)
-                    if d != domain and len(self._queues[d]) >= mv]
-        if not eligible:
-            return None
-        return self._pick_eligible(eligible)
-
-    # -- fast flat scans ----------------------------------------------------
-    def _pick_victim_flat_fast(self, domain: int, mv: int) -> Optional[int]:
-        m = self._ne_mask
-        if not m:
-            return None
+    # -- flat scans ---------------------------------------------------------
+    def _pick_victim_flat(self, domain: int, mv: int) -> Optional[int]:
+        m = self._ne_mask                # nonzero: dequeue checked it
         order = self.steal_order
         if order == "cyclic":
-            # first set bit after the caller's, wrapping — exactly the
-            # first hit of the reference (domain+1 .. domain-1) visit
-            # order, found by two's-complement bit tricks instead of a
-            # Python loop (``x & -x`` isolates the lowest set bit)
+            # first set bit after the caller's, wrapping — the first hit
+            # of the (domain+1 .. domain-1) visit order, found by two's-
+            # complement bit tricks instead of a Python loop (``x & -x``
+            # isolates the lowest set bit)
             m &= ~(1 << domain)          # never self-steal
             higher = m >> (domain + 1)
             if mv == 1:
@@ -413,7 +372,7 @@ class DomainQueues:
         """Lazy-heap form of ``max(eligible, key=(depth, -d))``: every depth
         change pushed ``(-depth, d)``, so the shallowest key whose entry
         still matches the live depth is the true maximum (heap order breaks
-        depth ties on lowest domain id, same as the reference)."""
+        depth ties on lowest domain id, as the ``max`` does)."""
         heap = self._order_heap
         qs = self._queues
         shelved: list[tuple[float, int]] = []
@@ -461,13 +420,13 @@ class DomainQueues:
     def _pick_victim_nearest(self, domain: int, min_victim: MinVictim,
                              topo) -> Optional[int]:
         """Nearest-first scan: tiers visited in ascending distance order,
-        the configured steal order applied only within a tier.  The fast
-        path skips tiers whose nonempty-peer counter is zero (no peer could
-        pass any depth gate); within a tier the reference selection runs
-        unchanged, so visit order and RNG draws are preserved exactly."""
+        the configured steal order applied only within a tier.  Tiers whose
+        nonempty-peer counter is zero are skipped (no peer could pass any
+        depth gate); within a tier the victim is chosen by a plain scan, so
+        visit order and RNG draws match a scan of every tier."""
         lvl = self._lvl_nonempty
         for level in range(1, topo.num_levels + 1):
-            if lvl is not None and not lvl[domain][level - 1]:
+            if not lvl[domain][level - 1]:
                 continue
             mv = self._level_min(min_victim, level)
             if mv is None:

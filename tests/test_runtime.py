@@ -6,10 +6,19 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.core import LocalityQueues
 from repro.runtime import (AdaptiveSteal, DomainQueues, Event, EventLog,
-                           Executor, NoSteal, ReferenceEventLog,
-                           SubmissionPool)
+                           Executor, NoSteal, SubmissionPool)
+from runtime_reference import (ReferenceEventLog, ReferenceQueues,
+                               reference_executor)
+
+
+def _cyclic(n):
+    return DomainQueues(n, steal_order="cyclic")
+
+
+def _pair(got):
+    """A dequeue as ``(item, stolen)``, or None."""
+    return None if got is None else (got.item, got.stolen)
 
 
 class TestLocalityQueuesEdgeCases:
@@ -17,44 +26,42 @@ class TestLocalityQueuesEdgeCases:
         # caller in LD 2 of 4; work only in LDs 0 and 3.  The cyclic scan
         # starts right after the local domain: 3 -> 0 -> 1, so LD 3 is hit
         # first even though LD 0 was filled first.
-        q = LocalityQueues(4)
+        q = _cyclic(4)
         q.enqueue(10, 0)
         q.enqueue(30, 3)
-        blk, stolen = q.dequeue(2)
-        assert (blk, stolen) == (30, True)
-        blk, stolen = q.dequeue(2)
-        assert (blk, stolen) == (10, True)
+        assert _pair(q.dequeue(2)) == (30, True)
+        assert _pair(q.dequeue(2)) == (10, True)
 
     def test_steal_scan_wraps_past_zero(self):
         # caller in LD 1 of 3; scan order is 2 -> 0 (wraps past the end)
-        q = LocalityQueues(3)
+        q = _cyclic(3)
         q.enqueue(7, 0)
-        assert q.dequeue(1) == (7, True)
+        assert _pair(q.dequeue(1)) == (7, True)
 
     def test_dequeue_all_empty(self):
-        q = LocalityQueues(3)
+        q = _cyclic(3)
         for ld in range(3):
             assert q.dequeue(ld) is None
         assert len(q) == 0
         # drained queues behave the same as never-filled ones
         q.enqueue(1, 0)
-        assert q.dequeue(0) == (1, False)
+        assert _pair(q.dequeue(0)) == (1, False)
         assert q.dequeue(0) is None
         assert len(q) == 0
 
     def test_local_pop_preferred_and_fifo(self):
-        q = LocalityQueues(2)
+        q = _cyclic(2)
         for blk in (1, 2, 3):
             q.enqueue(blk, 1)
         q.enqueue(9, 0)
-        assert q.dequeue(1) == (1, False)       # FIFO within the LD
-        assert q.dequeue(1) == (2, False)
-        assert q.dequeue(0) == (9, False)       # local wins while nonempty
-        assert q.dequeue(0) == (3, True)
+        assert _pair(q.dequeue(1)) == (1, False)    # FIFO within the LD
+        assert _pair(q.dequeue(1)) == (2, False)
+        assert _pair(q.dequeue(0)) == (9, False)    # local wins while nonempty
+        assert _pair(q.dequeue(0)) == (3, True)
 
     def test_sizes_consistent_under_interleaving(self):
         rng = np.random.default_rng(42)
-        q = LocalityQueues(4)
+        q = _cyclic(4)
         live = 0
         for step in range(500):
             if rng.random() < 0.55:
@@ -101,6 +108,14 @@ class TestDomainQueues:
     def test_random_steal_needs_rng(self):
         with pytest.raises(ValueError):
             DomainQueues(2, steal_order="random")
+
+    @pytest.mark.parametrize("domain", [-1, 3])
+    def test_enqueue_refuses_domain_out_of_range(self, domain):
+        q = DomainQueues(3)
+        with pytest.raises(ValueError, match=f"domain {domain} outside the "
+                                             "queues' 3 domains"):
+            q.enqueue("x", domain)
+        assert len(q) == 0 and q.queue_sizes() == [0, 0, 0]
 
 
 class TestSubmissionPool:
@@ -291,7 +306,7 @@ class TestRuntimeJacobiPath:
             assert stats.executed == 8
 
 
-# -- queued-cost snapshot accounting and the fast/slow contract --------------
+# -- queued-cost snapshot accounting -----------------------------------------
 
 class _MutableTask:
     """Task stand-in whose ``cost`` can be rewritten while queued."""
@@ -389,16 +404,16 @@ class TestColumnarEventLogEquivalence:
                      penalty=float(i % 2))
 
     def test_matches_reference_log_through_overflow(self):
-        fast, ref = EventLog(maxlen=16), ReferenceEventLog(maxlen=16)
+        log, ref = EventLog(maxlen=16), ReferenceEventLog(maxlen=16)
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)
-            self._emit_mixed(fast)
+            self._emit_mixed(log)
             self._emit_mixed(ref)
-        assert list(fast) == list(ref)
-        assert fast.counts() == ref.counts()
-        assert (fast.total, fast.dropped) == (ref.total, ref.dropped)
-        assert fast.tail(5) == ref.tail(5)
-        assert fast.to_csv_lines() == ref.to_csv_lines()
+        assert list(log) == list(ref)
+        assert log.counts() == ref.counts()
+        assert (log.total, log.dropped) == (ref.total, ref.dropped)
+        assert log.tail(5) == ref.tail(5)
+        assert log.to_csv_lines() == ref.to_csv_lines()
 
     def test_columns_export_matches_events_and_types(self):
         log = EventLog(maxlen=16)
@@ -426,21 +441,22 @@ class TestColumnarEventLogEquivalence:
         assert all(len(v) == 0 for v in cols.values())
 
 
-# -- randomized fast/slow equivalence (always-on seeded + hypothesis) --------
+# -- randomized equivalence with the linear-scan reference (seeded + hypothesis)
 
 def _run_equivalence_trial(seed: int, topo=None):
-    """One randomized interleaving driven through ``fast=True`` and
-    ``fast=False`` queues in lockstep: every Popped, the queue sizes, the
-    cost accounts (held to the exact shadow snapshot sum), and the RNG
-    state must stay identical — including under mid-queue cost mutation."""
+    """One randomized interleaving driven through ``DomainQueues`` and the
+    linear-scan ``ReferenceQueues`` in lockstep: every Popped, the queue
+    sizes, the cost accounts (held to the exact shadow snapshot sum), and
+    the RNG state must stay identical — including under mid-queue cost
+    mutation."""
     import random
 
     r = random.Random(seed)
     nd = topo.num_domains if topo is not None else r.choice([1, 2, 3, 4, 8])
     order = r.choice(DomainQueues.STEAL_ORDERS)
     rngs = [np.random.default_rng(seed) for _ in range(2)]
-    pair = [DomainQueues(nd, steal_order=order, rng=g, topology=topo,
-                         fast=f) for g, f in zip(rngs, (True, False))]
+    pair = [cls(nd, steal_order=order, rng=g, topology=topo)
+            for g, cls in zip(rngs, (DomainQueues, ReferenceQueues))]
     shadow = [0.0] * nd          # exact replay of the account arithmetic
     snaps = {}                   # uid -> enqueue-time cost snapshot
     live = []
@@ -482,9 +498,10 @@ def _run_equivalence_trial(seed: int, topo=None):
 
 
 class TestFastSlowEquivalenceRandomized:
-    """Always-on seeded sweep of the fast/slow bit-identity contract (the
-    hypothesis property below explores further when hypothesis is
-    installed; this fallback keeps the contract gated everywhere)."""
+    """Always-on seeded sweep of the bit-identity contract between the
+    runtime and the linear-scan reference (the hypothesis property below
+    explores further when hypothesis is installed; this fallback keeps the
+    contract gated everywhere)."""
 
     def test_flat_topologies(self):
         for seed in range(60):
@@ -502,13 +519,14 @@ class TestFastSlowEquivalenceRandomized:
 
     def test_executor_level_equivalence_all_policies(self):
         # whole-executor check: identical stats, event streams, and results
-        # across fast/slow for every steal order (events compared through
-        # the columnar vs reference log CSV, so this also pins the logs)
+        # against the reference queue and log for every steal order (events
+        # compared through the columnar vs reference log CSV, so this also
+        # pins the logs)
         for order in DomainQueues.STEAL_ORDERS:
             snaps = {}
-            for fast in (True, False):
-                ex = Executor(4, steal_order=order, seed=7, fast=fast,
-                              steal_penalty=lambda t, w: 4.0)
+            for make in (Executor, reference_executor):
+                ex = make(4, steal_order=order, seed=7,
+                          steal_penalty=lambda t, w: 4.0)
                 rng = np.random.default_rng(42)
                 for i in range(200):
                     home = int(rng.integers(-1, 4))
@@ -518,10 +536,29 @@ class TestFastSlowEquivalenceRandomized:
                     if i % 3 == 0:
                         ex.step()
                 ex.run_until_drained()
-                snaps[fast] = (dataclasses.asdict(ex.stats),
+                snaps[make] = (dataclasses.asdict(ex.stats),
                                ex.events.counts(),
                                tuple(ex.events.to_csv_lines()))
-            assert snaps[True] == snaps[False], order
+            assert snaps[Executor] == snaps[reference_executor], order
+
+    def test_executor_equivalence_contended(self):
+        # every task homed on domain 0, batch 4, cyclic, constant penalty:
+        # worker 0 takes each wave of four whole, so every other worker's
+        # grab is a victim scan over a machine with no foreign work
+        snaps = {}
+        for make in (Executor, reference_executor):
+            ex = make(4, steal_order="cyclic", batch=4,
+                      steal_penalty=lambda t, w: 4.0)
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore", RuntimeWarning)
+                for i in range(20_000):
+                    ex.submit(ex.make_task(home=0))
+                    if i % 4 == 3:
+                        ex.step()
+                ex.run_until_drained()
+            snaps[make] = (ex.metrics.snapshot(), ex.events.counts(),
+                           tuple(ex.events.to_csv_lines()))
+        assert snaps[Executor] == snaps[reference_executor]
 
 
 class TestFastSlowEquivalenceHypothesis:

@@ -6,10 +6,12 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import (ISTANBUL, NEHALEM_EP, BlockGrid, LocalityQueues,
+from repro.core import (ISTANBUL, NEHALEM_EP, BlockGrid,
                         OpenMPLocalityQueues, OpenMPTasking,
-                        StaticWorksharing, build_assignment, maxmin_rates,
-                        place, round_robin_assignment, simulate)
+                        StaticWorksharing, TBBLocalityQueues,
+                        build_assignment, maxmin_rates, place,
+                        round_robin_assignment, simulate)
+from repro.runtime import DomainQueues
 
 TOPOS = [ISTANBUL, NEHALEM_EP]
 
@@ -26,7 +28,8 @@ def small_grids(draw):
 class TestSimulatorConservation:
     @settings(max_examples=12, deadline=None)
     @given(grid=small_grids(),
-           policy_kind=st.sampled_from(["static_ws", "omp_task", "omp_lq"]),
+           policy_kind=st.sampled_from(["static_ws", "omp_task", "omp_lq",
+                                        "tbb_lq"]),
            placement=st.sampled_from(["serial", "static", "static1",
                                       "round_robin"]),
            order=st.sampled_from(["ijk", "kji"]),
@@ -41,6 +44,8 @@ class TestSimulatorConservation:
             pol = StaticWorksharing()
         elif policy_kind == "omp_task":
             pol = OpenMPTasking(submit_order=order, pool_cap=16)
+        elif policy_kind == "tbb_lq":
+            pol = TBBLocalityQueues()
         else:
             pol = OpenMPLocalityQueues(submit_order=order, pool_cap=16)
 
@@ -61,7 +66,7 @@ class TestSimulatorConservation:
     @given(grid=small_grids(), seed=st.integers(0, 3))
     def test_locality_queue_steals_only_when_local_empty(self, grid, seed):
         topo = ISTANBUL
-        q = LocalityQueues(topo.num_domains)
+        q = DomainQueues(topo.num_domains, steal_order="cyclic")
         rng = np.random.default_rng(seed)
         homes = rng.integers(0, topo.num_domains, grid.num_blocks)
         for blk in range(grid.num_blocks):
@@ -70,7 +75,7 @@ class TestSimulatorConservation:
             local_size = q.queue_sizes()[ld]      # live, pre-dequeue
             got = q.dequeue(ld)
             assert got is not None
-            blk, stolen = got
+            blk, stolen = got.item, got.stolen
             if local_size > 0:
                 assert not stolen and homes[blk] == ld
             else:

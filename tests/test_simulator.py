@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import (ISTANBUL, NEHALEM_EP, NEHALEM_EX, SMALL_GRID, TESTBED,
-                        OpenMPLocalityQueues, OpenMPTasking, StaticWorksharing,
+                        BlockGrid, OpenMPLocalityQueues, OpenMPTasking, StaticWorksharing,
                         TBBLocalityQueues, TBBParallelFor, place, run_samples,
                         simulate, stream_sanity, summarize, tbb_first_touch)
 
@@ -88,6 +88,42 @@ class TestOpenMPTasking:
                      homes, seed=1)
         assert r.mlups < 0.75 * ft
         assert r.steal_fraction > 0.1
+
+
+class TestHomelessBlocks:
+    """``round_robin`` placement homes every block at -1 (pages interleaved
+    over every domain); the locality-queue policies queue such a block on
+    domain ``blk % num_domains``."""
+
+    @pytest.mark.parametrize("make", [
+        lambda: OpenMPLocalityQueues(submit_order="ijk"),
+        lambda: OpenMPLocalityQueues(submit_order="kji"),
+        TBBLocalityQueues,
+    ], ids=["omp_lq_ijk", "omp_lq_kji", "tbb_lq"])
+    def test_every_block_executed_once_interleaved(self, make):
+        grid = BlockGrid(ni=8, nj=8, nk=16, di=2, dj=2, dk=16)   # 4x4 blocks
+        topo = NEHALEM_EP
+        homes = place("round_robin", grid, topo)
+        assert (homes == -1).all()
+        pol = make()
+        executed = []
+        orig_pop = pol.pop
+
+        def spy_pop(thread):
+            got = orig_pop(thread)
+            if got is not None:
+                executed.append(got.block)
+            return got
+
+        pol.pop = spy_pop
+        r = simulate(grid, topo, pol, homes, seed=0)
+        assert sorted(executed) == list(range(grid.num_blocks))
+        assert r.makespan_s > 0
+
+    def test_interleaved_block_queued_by_block_id(self):
+        from repro.core.scheduler import queue_domain
+        assert [queue_domain(b, -1, 4) for b in range(6)] == [0, 1, 2, 3, 0, 1]
+        assert queue_domain(5, 2, 4) == 2
 
 
 class TestTBB:
