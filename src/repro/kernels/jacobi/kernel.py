@@ -27,6 +27,15 @@ boundaries are Dirichlet-zero, applied by masking the clamped neighbour
 contributions.  The six neighbour terms are summed in logical (i, j, k)
 order whatever the stored order, so every order gives the same bits.
 
+Slab faces: an SPMD sweep runs the kernel on one chip's rows of the
+lattice, whose i-faces are not lattice faces.  ``halo=(up, down)`` gives
+the planes beyond them: logical axis i is cut into ``nseg`` equal segments,
+and a block whose first (last) row starts (ends) a segment takes its row -1
+(+1) from ``up[seg]`` (``down[seg]``) instead of a neighbour block or zero.
+Each halo is one more (1, dj, n) BlockSpec, so a grid step reads two more
+rows.  Halos need logical i stored major (axis 0), as every shard of the
+SPMD sweeps and every CPU array is.
+
 Block shape: the chip's compiler requires the last two block dims (dj and
 the minor extent) to be multiples of (8, 128) or equal to the array's dims,
 so the paper's 10 is refused for dj and the default block is (10, 8), the
@@ -46,15 +55,19 @@ from jax.experimental import pallas as pl
 
 
 def _jacobi_kernel(c_ref, center_ref, north_ref, south_ref, west_ref,
-                   east_ref, out_ref, *, nbi: int, nbj: int,
-                   order: tuple[int, int, int]):
+                   east_ref, *refs, nbi: int, nbj: int,
+                   order: tuple[int, int, int], bps: int | None):
     """One (di, dj, n) output block of the lattice in stored order.
 
     north/south are the -1/+1 neighbour blocks along stored axis 0;
     west/east along stored axis 1.  Index maps clamp at the lattice edge;
     masks zero the out-of-domain contributions (Dirichlet).  Stored axis s
-    is logical axis ``order[s]``.
+    is logical axis ``order[s]``.  With halos, ``refs`` starts with the
+    up/down halo rows of this block's segment of ``bps`` blocks, and they
+    replace the north/south rows at the segment's faces.
     """
+    out_ref = refs[-1]
+    up_ref, down_ref = refs[:-1] or (None, None)
     bi = pl.program_id(0)
     bj = pl.program_id(1)
     c = c_ref[0]
@@ -63,15 +76,20 @@ def _jacobi_kernel(c_ref, center_ref, north_ref, south_ref, west_ref,
     di, dj, n = centre.shape
     dtype = centre.dtype
 
+    def face(row, at_face, halo_ref):
+        """``row``, or at a face the halo row, else zero (Dirichlet)."""
+        fill = jnp.zeros_like(row) if halo_ref is None else halo_ref[0]
+        return jnp.where(at_face, fill, row)
+
     # stored axis 0: previous row comes from centre shifted, with row 0
-    # filled from the north block's last row (or zero at the edge).
-    north_last = north_ref[di - 1, :, :]
-    north_last = jnp.where(bi == 0, jnp.zeros_like(north_last), north_last)
+    # filled from the north block's last row (or the face's halo or zero).
+    north_last = face(north_ref[di - 1, :, :],
+                      bi == 0 if bps is None else bi % bps == 0, up_ref)
     up = jnp.concatenate([north_last[None], centre[:-1]], axis=0)
 
-    south_first = south_ref[0, :, :]
-    south_first = jnp.where(bi == nbi - 1, jnp.zeros_like(south_first),
-                            south_first)
+    south_first = face(south_ref[0, :, :],
+                       bi == nbi - 1 if bps is None else (bi + 1) % bps == 0,
+                       down_ref)
     down = jnp.concatenate([centre[1:], south_first[None]], axis=0)
 
     # stored axis 1.
@@ -100,7 +118,8 @@ def _jacobi_kernel(c_ref, center_ref, north_ref, south_ref, west_ref,
                    static_argnames=("di", "dj", "interpret", "order"))
 def jacobi_sweep_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
                         di: int = 10, dj: int = 8, interpret: bool = False,
-                        order: tuple[int, int, int] = (0, 1, 2)
+                        order: tuple[int, int, int] = (0, 1, 2),
+                        halo: tuple[jnp.ndarray, jnp.ndarray] | None = None
                         ) -> jnp.ndarray:
     """One Jacobi sweep over a (Ni, Nj, Nk) lattice stored in ``order``.
 
@@ -111,6 +130,12 @@ def jacobi_sweep_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
     ``order`` is the stored order both transposes are bitcasts.  Runs the
     compiled kernel; ``interpret=True`` executes the kernel body in Python
     instead (CPU tests).
+
+    ``halo=(up, down)``, each (nseg, Nj, Nk), cuts axis i into ``nseg``
+    equal segments and gives the planes before and after each; the i-faces
+    of the segments take them in place of the Dirichlet zeros.  Each
+    segment's rows must be a multiple of ``di``, and i must be stored
+    major (``order[0] == 0``).
     """
     g = jnp.transpose(f, order)
     n0, n1, n2 = g.shape
@@ -118,6 +143,19 @@ def jacobi_sweep_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
         raise ValueError(f"lattice {f.shape}, stored as {g.shape}, not "
                          f"divisible by block ({di},{dj})")
     nbi, nbj = n0 // di, n1 // dj
+    bps = None
+    if halo is not None:
+        nseg = halo[0].shape[0]
+        want = (nseg, *f.shape[1:])
+        if order[0] != 0:
+            raise ValueError(f"halos need axis i stored major, not {order}")
+        if any(h.shape != want for h in halo) or n0 % nseg:
+            raise ValueError(f"halos {[h.shape for h in halo]} do not cut "
+                             f"lattice {f.shape} into equal segments")
+        if (n0 // nseg) % di:
+            raise ValueError(f"segments of {n0 // nseg} rows not divisible "
+                             f"by di={di}")
+        bps = n0 // nseg // di
 
     def centre_map(bi, bj):
         return (bi, bj, 0)
@@ -145,7 +183,15 @@ def jacobi_sweep_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
         pl.BlockSpec(block, west_map),
         pl.BlockSpec(block, east_map),
     ]
-    kern = functools.partial(_jacobi_kernel, nbi=nbi, nbj=nbj, order=order)
+    operands = [c_arr, g, g, g, g, g]
+    if halo is not None:
+        def halo_map(bi, bj):
+            return (bi // bps, bj, 0)
+
+        in_specs += [pl.BlockSpec((1, dj, n2), halo_map)] * 2
+        operands += [jnp.transpose(h, order) for h in halo]
+    kern = functools.partial(_jacobi_kernel, nbi=nbi, nbj=nbj, order=order,
+                             bps=bps)
     out = pl.pallas_call(
         kern,
         grid=(nbi, nbj),
@@ -153,5 +199,5 @@ def jacobi_sweep_pallas(f: jnp.ndarray, c: jnp.ndarray | float = 1.0 / 6.0,
         out_specs=pl.BlockSpec(block, centre_map),
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
         interpret=interpret,
-    )(c_arr, g, g, g, g, g)
+    )(*operands)
     return jnp.transpose(out, tuple(order.index(a) for a in range(3)))

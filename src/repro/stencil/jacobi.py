@@ -15,12 +15,17 @@ the lattice's i-axis is decomposed into slabs of blocks, and the
     remote planes — the halo volume (and hence the collective roofline term
     of the compiled HLO) inflates by ~``blocks_per_dev``x.
 
-Both SPMD sweeps are XLA sweeps: each device applies the jitted
-``jacobi_sweep_ref`` to its halo-padded slabs (the Pallas kernel is the
-single-device path, ``repro.kernels.jacobi.ops.jacobi_sweep``).  The
-schedule builder of ``repro.core.assignment`` chooses the contiguous slabs
-when given block homes, demonstrating the end-to-end path
-placement → locality queues → SPMD assignment → fewer collective bytes.
+Both SPMD sweeps run the Pallas kernel (``repro.kernels.jacobi.kernel``),
+one call per chip per sweep, on the chip's rows of the lattice as they are
+stored: the kernel reads the exchanged halo planes at the slab faces as
+operands of their own, so no chip builds a halo-padded copy of its slabs.
+The block is the paper's (10, 8), cut to the slab: ``di`` is the largest
+divisor of a slab's rows up to 10, so each slab face is a block edge.  The
+kernel is compiled where the mesh's devices are TPUs and interpreted
+elsewhere (the CPU tests).  The schedule builder of
+``repro.core.assignment`` chooses the contiguous slabs when given block
+homes, demonstrating the end-to-end path placement → locality queues →
+SPMD assignment → fewer collective bytes.
 
 ``run_runtime_sweep`` adds a third, *online* execution path: slab updates
 submitted as tasks to the ``repro.runtime`` executor, with the paper's
@@ -35,8 +40,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax._src.mesh import get_concrete_mesh
 from jax.sharding import PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
 
+from ..kernels.jacobi.kernel import jacobi_sweep_pallas
+from ..kernels.jacobi.ops import stored_order
 from ..kernels.jacobi.ref import jacobi_sweep_ref
 from ..runtime import Executor, RuntimeStats, StealGovernor
 
@@ -66,22 +75,43 @@ def _halo_exchange(local: jnp.ndarray, axis: str) -> tuple[jnp.ndarray, jnp.ndar
     return up, down
 
 
+def _slab_sweep(f_local: jnp.ndarray, c: jnp.ndarray, up: jnp.ndarray,
+                down: jnp.ndarray) -> jnp.ndarray:
+    """The kernel's sweep of one chip's rows, cut into ``len(up)`` slabs
+    whose i-faces see the planes ``up`` and ``down``.
+
+    The block is the paper's (10, 8) fitted to the slab, and the kernel is
+    compiled on a TPU mesh and interpreted elsewhere; both are read from
+    the mesh's devices, and the stored order from their client, so a
+    compile for described TPUs on a CPU host gets the chip's program.
+    ``jax.sharding.get_mesh`` refuses inside ``jit`` and the abstract mesh
+    holds no device to ask, so the mesh comes from JAX's own lookup.
+    """
+    device = get_concrete_mesh().devices.flat[0]
+    order = stored_order(jax.ShapeDtypeStruct(
+        f_local.shape, f_local.dtype, sharding=SingleDeviceSharding(device)))
+    rows = f_local.shape[0] // up.shape[0]
+    di = max(d for d in range(1, 11) if rows % d == 0)
+    n1 = f_local.shape[order[1]]
+    dj = 8 if n1 % 8 == 0 else n1
+    return jacobi_sweep_pallas(f_local, c, di=di, dj=dj,
+                               interpret=device.platform != "tpu",
+                               order=order, halo=(up, down))
+
+
 def make_contiguous_sweep(cfg: JacobiGridConfig):
     """shard_map'd sweep with contiguous slab ownership (locality schedule)."""
 
     def sweep_local(f_local: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
         up, down = _halo_exchange(f_local, cfg.axis)
-        padded = jnp.concatenate([up[None], f_local, down[None]], axis=0)
-        # interior update on the padded slab, then crop the halo rows: the
-        # ref applies Dirichlet at the padded-slab boundary, but rows 0/-1
-        # of the crop saw the true halo planes, so values are exact.
-        return jacobi_sweep_ref(padded)[1:-1]
+        return _slab_sweep(f_local, c, up[None], down[None])
 
     def sweep(f: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
         return jax.shard_map(
             sweep_local,
             in_specs=(P(cfg.axis, None, None), P()),
             out_specs=P(cfg.axis, None, None),
+            check_vma=False,    # the kernel's interpret mode cannot track it
         )(f, c)
 
     return sweep
@@ -107,31 +137,27 @@ def make_scattered_sweep(cfg: JacobiGridConfig, blocks_per_dev: int):
         # every device needs planes from (almost) every other: all-gather.
         all_bounds = jax.lax.all_gather(bounds, axis)   # (n, bpd, 2, nj, nk)
 
-        def halo_for(slab_global_idx):
-            total = n * blocks_per_dev
-            prev_g = slab_global_idx - 1
-            next_g = slab_global_idx + 1
-            # global slab g is owned by device g % n as its (g // n)-th slab
-            def plane(g, which):
-                g_c = jnp.clip(g, 0, total - 1)
-                p = all_bounds[g_c % n, g_c // n, which]
-                valid = (g >= 0) & (g < total)
-                return jnp.where(valid, p, jnp.zeros_like(p))
-            return plane(prev_g, 1), plane(next_g, 0)
+        # my slabs are global slabs d, d+n, ... (strided ownership), and
+        # global slab g is owned by device g % n as its (g // n)-th slab
+        total = n * blocks_per_dev
+        mine = d + n * jnp.arange(blocks_per_dev)
 
-        outs = []
-        for b in range(blocks_per_dev):
-            g = d + b * n                      # strided ownership
-            up, down = halo_for(g)
-            padded = jnp.concatenate([up[None], slabs[b], down[None]], axis=0)
-            outs.append(jacobi_sweep_ref(padded)[1:-1])
-        return jnp.concatenate(outs, axis=0)
+        def planes(g, which):
+            g_c = jnp.clip(g, 0, total - 1)
+            p = all_bounds[g_c % n, g_c // n, which]    # (bpd, nj, nk)
+            valid = (g >= 0) & (g < total)
+            return jnp.where(valid[:, None, None], p, jnp.zeros_like(p))
+
+        # the last plane of the slab before each of mine, the first after
+        return _slab_sweep(f_local, c, planes(mine - 1, 1),
+                           planes(mine + 1, 0))
 
     def sweep(f: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
         return jax.shard_map(
             sweep_local,
             in_specs=(P(cfg.axis, None, None), P()),
             out_specs=P(cfg.axis, None, None),
+            check_vma=False,    # the kernel's interpret mode cannot track it
         )(f, c)
 
     return sweep

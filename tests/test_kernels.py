@@ -97,6 +97,77 @@ class TestJacobi:
             jacobi_sweep_pallas(f, di=4, dj=4)
 
 
+def _segments_ref(f, up, down, c=1 / 6):
+    """The reference sweep of each of ``len(up)`` equal segments of ``f``'s
+    rows, each padded with its halo planes, then cropped: the lattice the
+    segments and halos stand for."""
+    segs = jnp.split(f, up.shape[0], axis=0)
+    return jnp.concatenate([
+        jacobi_sweep_ref(jnp.concatenate([u[None], s, d[None]]), c)[1:-1]
+        for s, u, d in zip(segs, up, down)])
+
+
+class TestJacobiHalo:
+    """``halo=(up, down)``: the i-faces of each segment see its halo planes."""
+
+    @pytest.mark.parametrize("nseg", [1, 2, 4])
+    @pytest.mark.parametrize("halos", ["zero", "seeded"])
+    @pytest.mark.parametrize("order", [(0, 1, 2), (0, 2, 1)])
+    def test_matches_padded_segments_bitwise(self, nseg, halos, order):
+        rng = np.random.default_rng([nseg, len(halos), order[1]])
+        f = jnp.asarray(rng.standard_normal((16, 16, 24)), jnp.float32)
+        planes = (nseg, 16, 24)
+        if halos == "zero":
+            up = down = jnp.zeros(planes, jnp.float32)
+        else:
+            up, down = (jnp.asarray(rng.standard_normal(planes), jnp.float32)
+                        for _ in range(2))
+        out = jacobi_sweep_pallas(f, 1 / 6, di=4, dj=8, interpret=True,
+                                  order=order, halo=(up, down))
+        np.testing.assert_array_equal(out, _segments_ref(f, up, down))
+
+    def test_block_rows_below_the_segment(self):
+        """di divides a segment of 8 rows in two blocks: only the blocks
+        at the segment's faces take the halo rows."""
+        f = jnp.asarray(RNG.standard_normal((16, 8, 16)), jnp.float32)
+        up, down = (jnp.asarray(RNG.standard_normal((2, 8, 16)), jnp.float32)
+                    for _ in range(2))
+        out = jacobi_sweep_pallas(f, 0.25, di=4, dj=8, interpret=True,
+                                  halo=(up, down))
+        np.testing.assert_array_equal(out, _segments_ref(f, up, down, 0.25))
+
+    def test_no_halo_unchanged(self):
+        """One segment with zero halos is the Dirichlet lattice: the same
+        bits as the kernel without halos, and as the reference."""
+        f = jnp.asarray(RNG.standard_normal((20, 16, 24)), jnp.float32)
+        zero = jnp.zeros((1, 16, 24), jnp.float32)
+        base = jacobi_sweep_pallas(f, di=10, dj=8, interpret=True)
+        out = jacobi_sweep_pallas(f, di=10, dj=8, interpret=True,
+                                  halo=(zero, zero))
+        np.testing.assert_array_equal(out, base)
+        np.testing.assert_array_equal(base, jacobi_sweep_ref(f))
+
+    @pytest.mark.parametrize("order", [(1, 2, 0), (2, 0, 1), (1, 0, 2)])
+    def test_refuses_i_not_stored_major(self, order):
+        f = jnp.zeros((8, 8, 8), jnp.float32)
+        zero = jnp.zeros((2, 8, 8), jnp.float32)
+        with pytest.raises(ValueError, match="stored major"):
+            jacobi_sweep_pallas(f, di=4, dj=8, interpret=True, order=order,
+                                halo=(zero, zero))
+
+    @pytest.mark.parametrize("planes,di", [
+        ((5, 12, 8), 4),        # 12 rows do not cut into 5 segments
+        ((2, 12, 8), 4),        # segments of 6 rows, blocks of 4
+        ((2, 8, 8), 4),         # halo planes of the wrong width
+    ])
+    def test_refuses_segments_off_the_blocks(self, planes, di):
+        f = jnp.zeros((12, 12, 8), jnp.float32)
+        zero = jnp.zeros(planes, jnp.float32)
+        with pytest.raises(ValueError):
+            jacobi_sweep_pallas(f, di=di, dj=4, interpret=True,
+                                halo=(zero, zero))
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("b,hq,hkv,tq,tk,hd,causal,win,bq,bk", [
         (2, 4, 2, 128, 128, 32, True, 0, 64, 64),
